@@ -146,5 +146,7 @@ def paper_operating_points(steps=300):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import json
     print(json.dumps(paper_operating_points(), indent=2))

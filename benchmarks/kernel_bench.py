@@ -169,4 +169,6 @@ def main(json_path: str = BENCH_JSON):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

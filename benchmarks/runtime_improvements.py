@@ -57,5 +57,7 @@ def fig3_table(exit_rates: Dict[str, float] = None) -> Dict[str, Dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import json
     print(json.dumps(fig3_table(), indent=2))

@@ -20,9 +20,10 @@ is token-identical between the two paths (asserted per request).
 
 The MESH comparison (``mesh_table``) serves one stream through the
 mesh-aware engine on every shape of a forced 4-device host that fits
-(1x1 / dp2 / tp2 / dp2xtp2 / dp4 — run as a subprocess so the main
-process keeps its single real device), asserting greedy token identity
-with the single-device engine and recording tok/s per shape.
+(1x1 / dp2 / tp2 / dp2xtp2 / dp4 — in-process on an accelerator's real
+devices, or as a subprocess with 4 virtual devices on a CPU host),
+asserting greedy token identity with the single-device engine and
+recording tok/s per shape.
 
 The MoE comparison (``moe_table``) serves one stream through a MoE arch
 twice — dropless per-token decode (the default since PR 5) vs the legacy
@@ -680,6 +681,7 @@ def mesh_table(arch: str = "chatglm3-6b", capacity: int = 4,
     """
     from repro.configs.base import (AccelConfig, RunConfig, SHAPES_BY_NAME,
                                     get_arch)
+    from repro.dist.sharding import make_mesh
     from repro.launch.serve import SERVE_POLICY
     from repro.models import lm
     from repro.serve.scheduler import poisson_requests
@@ -696,7 +698,7 @@ def mesh_table(arch: str = "chatglm3-6b", capacity: int = 4,
     for name, dp, tp in MESH_SHAPES:
         if dp * tp > jax.device_count():
             continue
-        mesh = (jax.make_mesh((dp, tp), ("data", "model"))
+        mesh = (make_mesh((dp, tp), ("data", "model"))
                 if dp * tp > 1 else None)
         report, wall, kv_bytes, engine = _serve_workload(
             run, params, requests, capacity=capacity, max_len=max_len,
@@ -1127,18 +1129,20 @@ def main():
     ch = chunked_prefill_table(args.arch)
     _print_overload(ov, ch)
 
-    # per-mesh throughput: jax pins the device count at first init, so the
-    # mesh table runs in a SUBPROCESS with a forced 4-device host (the
-    # dryrun plays the same trick for its 512-device placeholders). The
-    # force flag only creates virtual devices on the CPU platform, so on an
-    # accelerator host with too few real devices the table is skipped, not
-    # failed — the slot/paged tables above remain the benchmark there.
+    # per-mesh throughput. On an accelerator the table runs IN-PROCESS on
+    # the real devices: this process already holds them, so a child could
+    # not. With fewer than 4 devices it covers too few shapes and the
+    # assert below fails the run. On the CPU (no device is exclusive) jax
+    # pins the device count at first init, so the table runs in a
+    # SUBPROCESS with a forced 4-device host (the dryrun plays the same
+    # trick for its 512-device placeholders).
     import os
     import subprocess
     import sys
     import tempfile
-    m = {}
-    if jax.default_backend() == "cpu" or jax.device_count() >= 4:
+    if jax.default_backend() != "cpu":
+        m = mesh_table(args.arch)
+    else:
         env = dict(os.environ)
         if "--xla_force_host_platform_device_count" not in env.get(
                 "XLA_FLAGS", ""):
@@ -1156,15 +1160,14 @@ def main():
                 m = json.load(f)
         finally:
             os.unlink(mesh_path)
-        for name, r in sorted(m.items()):
-            print(f"serving/mesh_{name},{r['wall_s']*1e6:.2f},"
-                  f"tok_per_s={r['tok_per_s']:.1f};devices={r['devices']};"
-                  f"dp={r['dp']};tp={r['tp']}")
-        assert len(m) >= 3, f"mesh table covered only {sorted(m)}"
-        print(f"mesh serving: {len(m)} shapes, all token-identical to 1x1")
-    else:
-        print(f"mesh serving: skipped ({jax.default_backend()} backend with "
-              f"{jax.device_count()} device(s) — needs CPU or >=4 devices)")
+    for name, r in sorted(m.items()):
+        print(f"serving/mesh_{name},{r['wall_s']*1e6:.2f},"
+              f"tok_per_s={r['tok_per_s']:.1f};devices={r['devices']};"
+              f"dp={r['dp']};tp={r['tp']}")
+    assert len(m) >= 3, (
+        f"mesh table covered only {sorted(m)} on {jax.device_count()} "
+        f"{jax.default_backend()} device(s) — it needs 4")
+    print(f"mesh serving: {len(m)} shapes, all token-identical to 1x1")
 
     # speculative decoding vs plain decode at small batch (distilled
     # 1-layer draft against the 8-layer target; see spec_table docstring)
@@ -1233,4 +1236,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -92,5 +92,7 @@ def table() -> Dict[str, Dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import json
     print(json.dumps(table(), indent=2))

@@ -52,8 +52,9 @@ target's own rollouts and measures ~0.88 acceptance at k=3, for 1.99x /
 ``repro.launch.serve --draft yi-9b --spec-k 3`` (prints the acceptance
 rate in the epilogue).
 
-Serve on a MESH: pass ``SlotEngine(..., mesh=jax.make_mesh((dp, tp),
-("data", "model")), sharding=ShardingPolicy(fsdp=False))`` — every jitted
+Serve on a MESH: pass ``SlotEngine(..., mesh=make_mesh((dp, tp),
+("data", "model")), sharding=ShardingPolicy(fsdp=False))`` (``make_mesh``
+from ``repro.dist.sharding``: every axis Auto) — every jitted
 entry point is built with explicit in/out shardings (params tp-sharded,
 the cache's slot axis over the data axes, page pools head-sharded, MoE
 expert stacks E-over-model) and greedy tokens stay identical to the
@@ -61,7 +62,7 @@ single-device engine. From the CLI:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
         PYTHONPATH=src python -m repro.launch.serve --arch yi-9b \
-        --mesh dp=2,model=2 [--temperature 0.8 --top-k 40]
+        --reduced --mesh dp=2,model=2 [--temperature 0.8 --top-k 40]
 
     PYTHONPATH=src python examples/quickstart.py [--arch yi-9b] [--steps 30]
 """

@@ -35,7 +35,7 @@ XH301  host sync inside a jit region: ``np.asarray``/``np.array`` on a
 XH401  XAIF bypass: ``repro.kernels.*`` imported from models/ or serve/ —
        model and engine code must dispatch through ``xaif.call`` so tuned
        policies, supports() fallbacks and the circuit breaker apply
-       (shared shape utils ``_tiling``/``_pltpu_compat`` are exempt).
+       (the shared shape utils in ``_tiling`` are exempt).
 XH501  missing donation: ``jax.jit`` of a function that takes AND returns
        a cache/state pytree without ``donate_argnums`` — the update
        allocates a second copy of the cache every call.
@@ -108,8 +108,7 @@ _DONATABLE = {"cache", "dcache", "slot_cache", "st", "state", "carry",
               "opt_state", "train_state"}
 _STATIC_ATTRS = {"shape", "dtype", "ndim", "size"}
 _DTYPE_CTORS = {"zeros": 1, "ones": 1, "empty": 1, "full": 2, "arange": 3}
-_ALLOWED_KERNEL_UTILS = {"repro.kernels._tiling",
-                         "repro.kernels._pltpu_compat"}
+_ALLOWED_KERNEL_UTILS = {"repro.kernels._tiling"}
 
 
 @dataclass

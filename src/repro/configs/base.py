@@ -96,8 +96,7 @@ class AccelConfig:
     a dict passed at construction is normalized to a sorted tuple of pairs
     so the config is hashable (usable as a ``jax.jit`` static argument and
     as a trace-cache key). Unlisted ops fall back to "ref" (pure jnp — the
-    "CPU-only" path of the paper). ``interpret`` runs Pallas kernels in
-    interpret mode (this container is CPU-only; on real TPU it is False).
+    "CPU-only" path of the paper).
 
     Superseded by the shape-aware ``xaif.DispatchPolicy`` (which a measured
     autotune produces — see core/autotune.py); both are accepted wherever a
@@ -108,7 +107,6 @@ class AccelConfig:
     # the frozen config hashes — read through backend_for(), not by indexing
     backends: "Mapping[str, str] | Tuple[Tuple[str, str], ...]" = field(
         default_factory=dict)
-    interpret: bool = True
 
     def __post_init__(self):
         object.__setattr__(

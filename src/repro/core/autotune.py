@@ -20,10 +20,11 @@ fewer iterations), and is recorded next to the measurement so reports can
 show measured-vs-modelled. Backends whose ``supports`` predicate rejects
 the cell, or that raise on it, are excluded from that cell only.
 
-CPU-container caveat: Pallas backends run in interpret mode here, whose
-timings are meaningless as TPU predictions — the ref/XLA backends will
-usually win, which is the *correct* measured answer for this host. On a
-real TPU the same sweep (``interpret=False``) selects the fused kernels.
+Off the TPU, Pallas backends run through the interpreter
+(``xaif.pallas_interpret``), whose timings are meaningless as TPU
+predictions — the ref/XLA backends will usually win, which is the
+*correct* measured answer for that host. On a TPU the same sweep times
+the compiled kernels.
 """
 from __future__ import annotations
 
@@ -543,7 +544,6 @@ class AutotuneResult:
 
 
 def autotune(ops: Optional[Sequence[str]] = None, *,
-             interpret: bool = True,
              iters: int = 3,
              scale: int = 1,
              tune_block_sizes: bool = False,
@@ -579,8 +579,7 @@ def autotune(ops: Optional[Sequence[str]] = None, *,
     change model numerics; serve-time quantization stays an explicit
     RunConfig choice (``weight_quant``), not an autotune side effect.
     """
-    baseline = baseline if baseline is not None else AccelConfig(
-        interpret=interpret)
+    baseline = baseline if baseline is not None else AccelConfig()
     want = set(ops) if ops else set(xaif.ops())
     say = print_fn or (lambda *_: None)
     all_cells = dict(CELLS)
@@ -633,7 +632,7 @@ def autotune(ops: Optional[Sequence[str]] = None, *,
                 kw = dict(tuning)
                 kw.update(kwargs)
                 if entry.takes_interpret:
-                    kw["interpret"] = interpret
+                    kw["interpret"] = xaif.pallas_interpret()
                 try:
                     fn = jax.jit(lambda *a, _f=entry.fn, _kw=kw: _f(*a, **_kw))
                     us = _time_call(fn, args, cell_iters)
@@ -659,18 +658,6 @@ def autotune(ops: Optional[Sequence[str]] = None, *,
             f"(static {must_measure}: {report.us_for(must_measure):.0f}us)")
         reports.append(report)
 
-    policy = xaif.DispatchPolicy.make(rules, interpret=interpret,
-                                      default=default)
+    policy = xaif.DispatchPolicy.make(rules, default=default)
     return AutotuneResult(policy=policy, cells=reports, baseline=baseline)
 
-
-def load_or_autotune(path: str = DEFAULT_POLICY_PATH,
-                     **kwargs) -> xaif.DispatchPolicy:
-    """Serve-startup helper: load a persisted policy if present, otherwise
-    run the sweep once and persist it."""
-    import os
-    if os.path.exists(path):
-        return xaif.DispatchPolicy.load(path)
-    result = autotune(**kwargs)
-    result.persist(path)
-    return result.policy

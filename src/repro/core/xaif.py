@@ -55,6 +55,8 @@ import json
 from dataclasses import dataclass
 from typing import Callable, Dict, Mapping, Optional, Tuple, Union
 
+import jax
+
 from repro.configs.base import AccelConfig
 
 # ---------------------------------------------------------------------------
@@ -270,7 +272,7 @@ class DispatchRule:
 
 @dataclass(frozen=True)
 class DispatchPolicy:
-    """(op, shape-bucket) -> DispatchRule, plus the interpret flag.
+    """(op, shape-bucket) -> DispatchRule.
 
     Frozen, hashable (usable as a ``jax.jit`` static argument / trace-cache
     key) and losslessly JSON-serializable. Lookup falls back
@@ -279,7 +281,6 @@ class DispatchPolicy:
     """
 
     rules: Tuple[Tuple[str, str, DispatchRule], ...] = ()
-    interpret: bool = True
     default: str = "ref"
 
     def __post_init__(self):
@@ -298,7 +299,7 @@ class DispatchPolicy:
     # -- construction -------------------------------------------------------
 
     @classmethod
-    def make(cls, table: Mapping, *, interpret: bool = True,
+    def make(cls, table: Mapping, *,
              default: str = "ref") -> "DispatchPolicy":
         """Build from {(op, bucket): backend | (backend, tuning) | rule}.
         A plain-string key ``op`` means the wildcard bucket."""
@@ -306,13 +307,12 @@ class DispatchPolicy:
         for key, val in dict(table).items():
             op, bucket = key if isinstance(key, tuple) else (key, WILDCARD)
             rules.append((op, bucket, val))
-        return cls(rules=tuple(rules), interpret=interpret, default=default)
+        return cls(rules=tuple(rules), default=default)
 
     @classmethod
     def from_accel(cls, accel: AccelConfig) -> "DispatchPolicy":
         """Lift a v1 static AccelConfig into a wildcard-bucket policy."""
-        return cls.make({op: name for op, name in dict(accel.backends).items()},
-                        interpret=accel.interpret)
+        return cls.make(dict(accel.backends))
 
     # -- lookup -------------------------------------------------------------
 
@@ -331,7 +331,6 @@ class DispatchPolicy:
     def to_json(self, **extra) -> str:
         doc = {
             "version": 2,
-            "interpret": self.interpret,
             "default": self.default,
             "rules": [
                 {"op": o, "bucket": b, "backend": r.backend,
@@ -349,8 +348,7 @@ class DispatchPolicy:
             (r["op"], r["bucket"],
              DispatchRule(r["backend"], tuple(r.get("tuning", {}).items())))
             for r in doc.get("rules", ()))
-        return cls(rules=rules, interpret=bool(doc.get("interpret", True)),
-                   default=str(doc.get("default", "ref")))
+        return cls(rules=rules, default=str(doc.get("default", "ref")))
 
     def save(self, path, **extra) -> None:
         with open(path, "w") as f:
@@ -368,6 +366,13 @@ PolicyLike = Union[AccelConfig, DispatchPolicy]
 # ---------------------------------------------------------------------------
 # Resolution + dispatch
 # ---------------------------------------------------------------------------
+
+
+def pallas_interpret() -> bool:
+    """Whether Pallas backends run through the interpreter: compiled on a
+    TPU, interpreted on every other platform. The platform decides — no
+    policy, config or flag carries this."""
+    return jax.default_backend() != "tpu"
 
 
 def get_entry(op: str, name: str) -> BackendEntry:
@@ -458,10 +463,8 @@ def call(op: str, policy: PolicyLike, *args, **kwargs):
         allowed = e.tunable_names
         m = {k: v for k, v in tuning if k in allowed}
         m.update(kwargs)
-        if e.takes_interpret and "interpret" not in m:
-            # Pallas backends take interpret= so the CPU container can run
-            # them.
-            m["interpret"] = policy.interpret
+        if e.takes_interpret:
+            m["interpret"] = pallas_interpret()
         return m
 
     breaker = _BREAKER

@@ -22,7 +22,7 @@ import contextlib
 from typing import Any, Optional, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ShardingPolicy
 
@@ -69,6 +69,15 @@ class ShardCtx:
 
 
 _CTX: list = []   # stack; [-1] is the active context
+
+
+def make_mesh(shape: Tuple[int, ...], names: Tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``. Since JAX 0.9 its axes
+    default to ``Explicit``, on which ``with_sharding_constraint`` (and so
+    :func:`constrain`) refuses to run; this module's layouts are
+    constraint-driven, so every mesh in the repo is built here."""
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def current_ctx() -> Optional[ShardCtx]:

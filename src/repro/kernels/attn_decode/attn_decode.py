@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pltpu_compat import compiler_params as _compiler_params
 from repro.kernels._tiling import divisor_block
 
 _NEG = -1e30
@@ -44,10 +43,12 @@ def _decode_kernel(cp_ref, q_ref, k_ref, v_ref, o_ref,
         q = q * scale
     k = k_ref[0].astype(jnp.float32)                        # [Hkv, bs, D]
     v = v_ref[0].astype(jnp.float32)                        # [Hkv, bs, Dv]
-    kr = jnp.repeat(k, g, axis=0)                           # [Hq, bs, D]
-    vr = jnp.repeat(v, g, axis=0)
-    s = jnp.einsum("hd,hpd->hp", q, kr,
-                   preferred_element_type=jnp.float32)      # [Hq, bs]
+    hkv = k.shape[0]
+    # GQA as a batched matmul over KV heads ([Hkv, g, D] query groups): the
+    # TPU lowering needs a non-contracting lhs dim, which "hd,hpd->hp" lacks
+    s = jnp.einsum("hgd,hpd->hgp", q.reshape(hkv, g, q.shape[-1]), k,
+                   preferred_element_type=jnp.float32
+                   ).reshape(hkv * g, bs)                 # [Hq, bs]
     if post_scale:
         s = s * scale
 
@@ -61,7 +62,8 @@ def _decode_kernel(cp_ref, q_ref, k_ref, v_ref, o_ref,
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
-        "hp,hpd->hd", p, vr, preferred_element_type=jnp.float32)
+        "hgp,hpd->hgd", p.reshape(hkv, g, bs), v,
+        preferred_element_type=jnp.float32).reshape(hkv * g, v.shape[-1])
     m_ref[...] = m_new
 
     @pl.when(bi == nb - 1)
@@ -121,7 +123,7 @@ def attn_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, dv), jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cache_pos, q, k, v)

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pltpu_compat import compiler_params as _compiler_params
 
 _NEG = -1e30
 
@@ -88,7 +87,7 @@ def entropy_pallas(logits: jax.Array, *, bm: int = 256, bv: int = 2048,
             pltpu.VMEM((bm, 1), jnp.float32),
             pltpu.VMEM((bm, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

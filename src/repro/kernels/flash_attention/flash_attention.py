@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pltpu_compat import compiler_params as _compiler_params
+from repro.kernels._tiling import ceil_mult, divisor_block, pad_to
+
 
 _NEG = -1e30
 
@@ -77,15 +78,20 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     assert hq % hkv == 0
     g = hq // hkv
     scale = scale if scale is not None else d ** -0.5
-    bq = min(bq, t)
-    while t % bq:
-        bq //= 2
+    # a query block that divides T, or — when that would be fewer than the
+    # 8 rows the TPU lowering needs (a 455-token prompt) — padded query
+    # rows, computed against real keys and sliced off
+    bq_ = divisor_block(t, min(bq, t))
+    if bq_ % 8 and bq_ != t:
+        bq_ = ceil_mult(t, bq)
+        q, _ = pad_to(q, bq_, 2)
+    bq, tpad = bq_, q.shape[2]
     bkv = min(bkv, s_len)
     spad = (s_len + bkv - 1) // bkv * bkv
     if spad != s_len:
         pad = ((0, 0), (0, 0), (0, spad - s_len), (0, 0))
         k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-    grid = (b, hq, t // bq, spad // bkv)
+    grid = (b, hq, tpad // bq, spad // bkv)
     kernel = functools.partial(
         _fa_kernel, nkv=grid[3], bq=bq, bkv=bkv, seq_q=t, seq_kv=s_len,
         causal=causal, scale=scale)
@@ -104,8 +110,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v)
+    )(q, k, v)[:, :, :t]

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pltpu_compat import compiler_params as _compiler_params
-
 from repro.kernels.gemm.ref import ACTIVATIONS
 
 
@@ -77,7 +75,7 @@ def gemm_pallas(x: jax.Array, w: jax.Array, bias: Optional[jax.Array] = None,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -134,7 +132,7 @@ def gemm_int8_pallas(xq: jax.Array, wq: jax.Array, x_scale: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

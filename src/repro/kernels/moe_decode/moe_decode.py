@@ -14,10 +14,12 @@ Unused tail blocks (expert id -1) write zeros and are never gathered back.
 Worst-case block count is static — ceil(B*K/bt) + E (every expert's run
 padded) — so the grid never re-traces as routing shifts between steps.
 
-VMEM per step @ bt=16, d=2048, h=768 (qwen3 full scale, bf16): x 64 KiB +
-3 weight panels ~9 MiB — inside the ~16 MiB budget; shrink ``bt`` has no
-effect on the panels, so the tunable trades dispatch padding against grid
-steps.
+Each grid step also takes one ``bh``-wide slice of the expert hidden dim
+and accumulates its down projection in an fp32 VMEM scratch, so VMEM per
+step @ bt=8, bh=256, d=2048 (qwen3 full scale, bf16) is x 32 KiB + 3 weight
+panel slices 3 MiB, double-buffered 6 MiB — whole [d, h] panels (18 MiB
+double-buffered) overran the 16 MiB scoped-VMEM limit. ``bt`` trades
+dispatch padding against grid steps; it has no effect on the panels.
 """
 from __future__ import annotations
 
@@ -28,30 +30,43 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pltpu_compat import compiler_params as _compiler_params
-from repro.kernels._tiling import sorted_run_ranks
+from repro.kernels._tiling import divisor_block, sorted_run_ranks
 
 
-def _moe_kernel(be_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
-    i = pl.program_id(0)
-    live = be_ref[i] >= 0
-    xb = x_ref[...].astype(jnp.float32)                       # [bt, d]
-    gact = xb @ wg_ref[0].astype(jnp.float32)                 # [bt, h]
-    up = xb @ wu_ref[0].astype(jnp.float32)
-    hidden = jax.nn.silu(gact) * up
-    out = hidden @ wd_ref[0].astype(jnp.float32)              # [bt, d]
-    o_ref[...] = jnp.where(live, out, 0.0).astype(o_ref.dtype)
+def _moe_kernel(be_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
+                nh: int):
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # one [bh] slice of the expert hidden dim: the SwiGLU is elementwise in
+    # h, so the down projection sums exactly over the h tiles
+    xb = x_ref[...]                                           # [bt, d]
+    gact = jnp.dot(xb, wg_ref[0], preferred_element_type=jnp.float32)
+    up = jnp.dot(xb, wu_ref[0], preferred_element_type=jnp.float32)
+    hidden = (jax.nn.silu(gact) * up).astype(wd_ref.dtype)   # [bt, bh]
+    acc_ref[...] += jnp.dot(hidden, wd_ref[0],
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(j == nh - 1)
+    def _finish():
+        o_ref[...] = jnp.where(be_ref[i] >= 0, acc_ref[...], 0.0)
 
 
 def moe_decode_pallas(x: jax.Array, expert_idx: jax.Array, gate: jax.Array,
                       w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-                      *, bt: int = 8, interpret: bool = False) -> jax.Array:
+                      *, bt: int = 8, bh: int = 256,
+                      interpret: bool = False) -> jax.Array:
     """x [B, d]; expert_idx [B, K] i32; gate [B, K] f32;
     w_gate/w_up [E, d, h]; w_down [E, h, d]. Returns fp32 [B, d].
 
+    ``bh`` tiles the expert hidden dim (a power-of-two divisor of h).
+
     Bitwise identity with the capacity path is the REF backend's contract,
     not this kernel's — like every Pallas kernel here it is validated by
-    allclose (fp32 throughout, vs the ref's mixed-precision accumulate).
+    allclose (weight-dtype MXU operands with fp32 accumulation).
     """
     b, d = x.shape
     k = expert_idx.shape[1]
@@ -79,25 +94,28 @@ def moe_decode_pallas(x: jax.Array, expert_idx: jax.Array, gate: jax.Array,
              .astype(jnp.int32) - 1)
     blk_e = jnp.where(starts < bounds[1:][jnp.maximum(blk_e, 0)], blk_e, -1)
 
+    bh = divisor_block(h, bh)
+    nh = h // bh
     out_pad = pl.pallas_call(
-        functools.partial(_moe_kernel),
+        functools.partial(_moe_kernel, nh=nh),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,          # blk_e
-            grid=(nb,),
+            grid=(nb, nh),
             in_specs=[
-                pl.BlockSpec((bt, d), lambda i, be: (i, 0)),
-                pl.BlockSpec((1, d, h),
-                             lambda i, be: (jnp.maximum(be[i], 0), 0, 0)),
-                pl.BlockSpec((1, d, h),
-                             lambda i, be: (jnp.maximum(be[i], 0), 0, 0)),
-                pl.BlockSpec((1, h, d),
-                             lambda i, be: (jnp.maximum(be[i], 0), 0, 0)),
+                pl.BlockSpec((bt, d), lambda i, j, be: (i, 0)),
+                pl.BlockSpec((1, d, bh),
+                             lambda i, j, be: (jnp.maximum(be[i], 0), 0, j)),
+                pl.BlockSpec((1, d, bh),
+                             lambda i, j, be: (jnp.maximum(be[i], 0), 0, j)),
+                pl.BlockSpec((1, bh, d),
+                             lambda i, j, be: (jnp.maximum(be[i], 0), j, 0)),
             ],
-            out_specs=pl.BlockSpec((bt, d), lambda i, be: (i, 0)),
+            out_specs=pl.BlockSpec((bt, d), lambda i, j, be: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((nb * bt, d), jnp.float32),
-        compiler_params=_compiler_params(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(blk_e, x_pad, w_gate, w_up, w_down)
 

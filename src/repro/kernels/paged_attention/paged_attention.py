@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pltpu_compat import compiler_params as _compiler_params
 
 _NEG = -1e30
 
@@ -43,10 +42,12 @@ def _paged_kernel(pt_ref, cp_ref, q_ref, k_ref, v_ref, o_ref,
         q = q * scale
     k = k_ref[0].astype(jnp.float32)                        # [Hkv, ps, D]
     v = v_ref[0].astype(jnp.float32)                        # [Hkv, ps, Dv]
-    kr = jnp.repeat(k, g, axis=0)                           # [Hq, ps, D]
-    vr = jnp.repeat(v, g, axis=0)
-    s = jnp.einsum("hd,hpd->hp", q, kr,
-                   preferred_element_type=jnp.float32)      # [Hq, ps]
+    hkv = k.shape[0]
+    # GQA as a batched matmul over KV heads ([Hkv, g, D] query groups): the
+    # TPU lowering needs a non-contracting lhs dim, which "hd,hpd->hp" lacks
+    s = jnp.einsum("hgd,hpd->hgp", q.reshape(hkv, g, q.shape[-1]), k,
+                   preferred_element_type=jnp.float32
+                   ).reshape(hkv * g, ps)                 # [Hq, ps]
     if post_scale:
         s = s * scale
 
@@ -61,7 +62,8 @@ def _paged_kernel(pt_ref, cp_ref, q_ref, k_ref, v_ref, o_ref,
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
-        "hp,hpd->hd", p, vr, preferred_element_type=jnp.float32)
+        "hgp,hpd->hgd", p.reshape(hkv, g, ps), v,
+        preferred_element_type=jnp.float32).reshape(hkv * g, v.shape[-1])
     m_ref[...] = m_new
 
     @pl.when(pi == np_ - 1)
@@ -132,7 +134,7 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, dv), jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, cache_pos, q, k_pages, v_pages)

@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 from repro.core import xaif
-from repro.kernels._tiling import divisor_block
+from repro.kernels._tiling import ceil_mult, divisor_block, pad_to
 from repro.kernels.rmsnorm import ref as _ref
 from repro.kernels.rmsnorm import rmsnorm as _k
 
@@ -25,7 +25,13 @@ def rmsnorm_pallas_op(x, scale, eps: float = 1e-5, *, interpret: bool = False,
     lead = x.shape[:-1]
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
-    # the single-pass kernel cannot pad rows: shrink to an exact divisor
-    bm_ = divisor_block(x2.shape[0], bm)
+    m = x2.shape[0]
+    # prefer a block that divides the rows exactly; the TPU lowering needs a
+    # multiple of 8 rows (or all of them), so odd counts (a 455-token
+    # prompt) are padded instead — rows are independent, pad rows dropped
+    bm_ = divisor_block(m, bm)
+    if bm_ % 8 and bm_ != m:
+        bm_ = ceil_mult(m, bm)
+        x2, _ = pad_to(x2, bm_, 0)
     out = _k.rmsnorm_pallas(x2, scale, eps, bm=bm_, interpret=interpret)
-    return out.reshape(*lead, d)
+    return out[:m].reshape(*lead, d)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pltpu_compat import compiler_params as _compiler_params
-
 
 def _ssm_kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
                 y_ref, hout_ref, h_ref, *, bt: int, nt: int, has_h0: bool):
@@ -98,7 +96,7 @@ def selective_scan_pallas(u, dt, a, b, c, d, h0=None, *, bt: int = 128,
             jax.ShapeDtypeStruct((bsz, din, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

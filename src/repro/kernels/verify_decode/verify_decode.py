@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pltpu_compat import compiler_params as _compiler_params
 from repro.kernels._tiling import divisor_block
 
 _NEG = -1e30
@@ -113,7 +112,7 @@ def verify_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, k1, dv), jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cache_pos, q, k, v)
@@ -201,7 +200,7 @@ def verify_decode_paged_pallas(q: jax.Array, k_pages: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, k1, dv), jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, cache_pos, q, k_pages, v_pages)

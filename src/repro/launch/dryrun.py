@@ -1,8 +1,12 @@
 import os
-# Run before ANY other import: jax locks the device count at first init and
-# the production meshes need 512 host placeholders. APPEND-if-absent — a
-# user-set XLA_FLAGS (e.g. the serving tests' forced 4-device host) must
-# never be clobbered by merely importing this module.
+# Run before ANY other import. The dry-run is a CPU-only analysis: pinning
+# the platform (inherited by the --all children) keeps it off any
+# accelerator the host has, whose single owner is the process serving on it.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# jax locks the device count at first init and the production meshes need
+# 512 host placeholders. APPEND-if-absent — a user-set XLA_FLAGS (e.g. the
+# serving tests' forced 4-device host) must never be clobbered by merely
+# importing this module.
 if "--xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (

@@ -12,14 +12,16 @@ from __future__ import annotations
 
 import jax
 
+from repro.dist.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices exist locally (tests / examples): 1D data mesh."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))

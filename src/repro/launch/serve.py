@@ -8,6 +8,10 @@ decode throughput plus per-request latency percentiles (p50/p99):
     PYTHONPATH=src python -m repro.launch.serve --arch chatglm3-6b \
         --requests 32 --capacity 8 --rate 4 [--gated] [--threshold 0.9]
 
+The arch is served at its published widths and depth, with random weights
+from a seed; ``--reduced`` swaps in the tiny same-family config
+(``ArchConfig.reduced()``) that a CPU host can run.
+
 ``--rate 0`` disables arrival pacing (closed-loop: every request is ready
 at t=0 — the pure-throughput configuration the benchmarks use).
 
@@ -29,7 +33,7 @@ force virtual devices first:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
         PYTHONPATH=src python -m repro.launch.serve --arch chatglm3-6b \
-        --mesh dp=2,model=2
+        --reduced --mesh dp=2,model=2
 
 ``--paged --prefix-sharing`` turns on the radix prefix index: prompts are
 matched against KV page chains left resident by earlier requests, matched
@@ -49,11 +53,11 @@ chunk size off the page grid, a recurrent arch with ``--prefill-chunk``)
 die at argument parsing with an actionable message.
 
 ``--draft ARCH --spec-k N`` turns on speculative decoding: the draft arch
-(reduced) proposes N tokens per live slot per round and the target model
-verifies all of them in ONE batched ``verify_decode`` forward; accepted
-prefixes advance multiple positions per chunk and KV pages grow by the
-accepted count. Greedy tokens are identical to plain decode; sampled
-requests go through residual rejection sampling (distribution-
+(reduced along with the target under ``--reduced``) proposes N tokens per
+live slot per round and the target model verifies all of them in ONE
+batched ``verify_decode`` forward; accepted prefixes advance multiple
+positions per chunk and KV pages grow by the accepted count. Greedy
+tokens are identical to plain decode; sampled requests go through residual rejection sampling (distribution-
 preserving). Rejected at parse time: unknown draft arch, ``--spec-k``
 below 1, vocab mismatch, recurrent/MLA/MoE archs on either side, and
 ``--gated``/``--threshold``/``--prefill-chunk`` combos (verification
@@ -71,14 +75,15 @@ Backend selection: by default the static all-"ref" AccelConfig. Pass
 measured sweep at startup — at THIS arch's exact serve-time dims (row ops
 at the slot capacity, its head layout, its paged-KV extent; the policy
 JSON records the arch per cell) — persisting to ``--policy``'s path
-(default ``.xaif_policy.json``) so the next launch skips the measurement.
+(default ``.xaif_policy.json``). A policy file is read only when
+``--policy`` names it. Pallas backends run compiled on a TPU and through
+the interpreter elsewhere (``xaif.pallas_interpret``).
 """
 from __future__ import annotations
 
 import argparse
 import contextlib
 import dataclasses
-import os
 
 import jax
 import numpy as np
@@ -88,7 +93,7 @@ from repro.configs.base import (AccelConfig, RunConfig, SHAPES_BY_NAME,
 from repro.core import autotune as autotune_mod
 from repro.core import xaif
 from repro.dist import sharding as shd
-from repro.models import lm
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import SlotEngine
 from repro.serve.overload import OverloadConfig
 from repro.serve.scheduler import poisson_requests, serve
@@ -113,12 +118,15 @@ def parse_mesh(spec: str):
             f"--mesh {spec} needs {need} devices but only "
             f"{jax.device_count()} are visible (on CPU prepend "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need})")
-    return jax.make_mesh((sizes["data"], sizes["model"]), ("data", "model"))
+    return shd.make_mesh((sizes["data"], sizes["model"]), ("data", "model"))
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_archs())
+    ap.add_argument("--reduced", action="store_true",
+                    help="reduced same-family config (CPU-runnable) instead "
+                         "of the published widths")
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--capacity", type=int, default=8)
     ap.add_argument("--rate", type=float, default=0.0,
@@ -180,8 +188,8 @@ def main():
     ap.add_argument("--sample-seed", type=int, default=0,
                     help="base seed of the per-slot sampling PRNG keys")
     ap.add_argument("--draft", default="", metavar="ARCH",
-                    help="speculative decoding: run this arch (reduced) as "
-                         "the draft model — k proposals per live slot per "
+                    help="speculative decoding: run this arch as the "
+                         "draft model — k proposals per live slot per "
                          "round, verified in ONE batched target forward; "
                          "greedy tokens stay identical to plain decode")
     ap.add_argument("--spec-k", type=int, default=None, metavar="N",
@@ -204,13 +212,16 @@ def main():
     ap.add_argument("--max-restarts", type=int, default=8,
                     help="supervisor restart budget before giving up")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--policy", default=autotune_mod.DEFAULT_POLICY_PATH,
-                    help="path to a persisted DispatchPolicy JSON")
+    ap.add_argument("--policy", default=None,
+                    help="serve under this persisted DispatchPolicy JSON "
+                         "(with --autotune: where to persist it, default "
+                         f"{autotune_mod.DEFAULT_POLICY_PATH})")
     ap.add_argument("--autotune", action="store_true",
                     help="run the measured backend sweep at startup — at "
                          "this arch's exact serve-time dims — and persist "
                          "the winning policy to --policy")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # invalid flag combinations die HERE with an actionable message, not on
     # an assert deep inside SlotEngine after the model has been built
@@ -309,16 +320,19 @@ def main():
                  "overload flags (--preemption/--priority/--prefill-chunk/"
                  "--slo-ttft-ms)")
 
+    def arch(name):
+        c = get_arch(name)
+        return c.reduced() if args.reduced else c
+
     if args.autotune:
-        arch_for_cells = get_arch(args.arch).reduced()
-        print(f"autotuning XAIF backends at {args.arch} serve dims "
-              f"-> {args.policy}")
+        path = args.policy or autotune_mod.DEFAULT_POLICY_PATH
+        print(f"autotuning XAIF backends at {args.arch} serve dims -> {path}")
         result = autotune_mod.autotune(
-            iters=2, arch=arch_for_cells, capacity=args.capacity,
+            iters=2, arch=arch(args.arch), capacity=args.capacity,
             max_len=args.max_len, page_size=args.page_size, print_fn=print)
-        result.persist(args.policy)
+        result.persist(path)
         policy = result.policy
-    elif os.path.exists(args.policy):
+    elif args.policy:
         policy = xaif.DispatchPolicy.load(args.policy)
         print(f"loaded dispatch policy from {args.policy} "
               f"({len(policy.rules)} rules)")
@@ -332,13 +346,10 @@ def main():
         # trace-time faults) so the dispatched-backend site actually fires
         policy = xaif.DispatchPolicy.make({"rmsnorm": "chaos"})
 
-    cfg = get_arch(args.arch).reduced()
+    cfg = arch(args.arch)
     if args.threshold is not None and cfg.early_exit is not None:
         cfg = dataclasses.replace(cfg, early_exit=dataclasses.replace(
             cfg.early_exit, entropy_threshold=args.threshold))
-    run = RunConfig(arch=cfg, shape=SHAPES_BY_NAME["decode_32k"],
-                    accel=policy)
-    params = lm.init_lm(jax.random.PRNGKey(0), cfg)
     gated = args.gated and all(b.mixer == "attn" for b in cfg.block_pattern)
 
     if args.prefill_chunk and not (
@@ -351,7 +362,7 @@ def main():
     spec = None
     if args.draft:
         from repro.serve.engine import SpecConfig
-        draft_cfg = get_arch(args.draft).reduced()
+        draft_cfg = arch(args.draft)
         for role, c, name in (("target", cfg, args.arch),
                               ("draft", draft_cfg, args.draft)):
             if not (all(b.mixer == "attn" for b in c.block_pattern)
@@ -369,12 +380,9 @@ def main():
             draft_cfg = dataclasses.replace(draft_cfg, early_exit=None)
         if cfg.early_exit is not None:
             # verification must score all k+1 positions with full-model
-            # logits; the exit merge has no verify equivalent — rebuild the
-            # run/params pair without the exit heads
+            # logits; the exit merge has no verify equivalent — serve the
+            # target without the exit heads
             cfg = dataclasses.replace(cfg, early_exit=None)
-            run = RunConfig(arch=cfg, shape=SHAPES_BY_NAME["decode_32k"],
-                            accel=policy)
-            params = lm.init_lm(jax.random.PRNGKey(0), cfg)
             print(f"spec decode: early-exit heads of {args.arch} disabled "
                   f"for serving (verification uses full-model logits)")
         spec = SpecConfig(draft_arch=draft_cfg, k=spec_k)
@@ -409,6 +417,8 @@ def main():
             r.prompt = np.concatenate([common, r.prompt])
 
     mesh = parse_mesh(args.mesh) if args.mesh else None
+    run = RunConfig(arch=cfg, shape=SHAPES_BY_NAME["decode_32k"],
+                    accel=policy)
     engine = SlotEngine(run, capacity=args.capacity, max_len=args.max_len,
                         chunk=args.chunk, gated=gated, paged=args.paged,
                         page_size=args.page_size,
@@ -417,6 +427,9 @@ def main():
                         temperature=args.temperature, top_k=args.top_k,
                         top_p=args.top_p, sample_seed=args.sample_seed,
                         prefix_sharing=args.prefix_sharing, spec=spec)
+    # built once, in the engine's layout: on a mesh each device only ever
+    # holds its own shard
+    params = engine.init_params(jax.random.PRNGKey(0))
     # the engine's jitted entries carry their own shardings; shard_ctx
     # around the stream simulator covers any ad-hoc constrain/device_put
     # in the serve path (identity when no mesh is installed)

@@ -15,10 +15,12 @@ import argparse
 
 from repro.configs.base import (AccelConfig, RunConfig, SHAPES_BY_NAME,
                                 get_arch, list_archs)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.trainer import train
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_archs())
     ap.add_argument("--shape", default="train_4k")

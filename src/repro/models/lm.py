@@ -167,6 +167,13 @@ def _apply_layer(p, x, spec: BlockSpec, cfg: ArchConfig, policy: xaif.PolicyLike
 
 
 def init_lm(key, cfg: ArchConfig) -> Dict:
+    """Random parameters for ``cfg`` from ``key``. Each stacked slot weight
+    is drawn layer by layer (``lax.map``), so only one layer's float32 draw
+    is alive at a time: a vmapped draw held a float32 temporary the size of
+    the whole stack (6.3 GB for chatglm3-6b's FFN), which does not fit
+    beside the bf16 weights on one 16 GB chip. Callers may wrap this in
+    ``jax.jit`` (e.g. with ``out_shardings``, see ``SlotEngine.init_params``)
+    to build each device's shard in place."""
     dtype = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, 8)
     params: Dict[str, Any] = {
@@ -181,13 +188,13 @@ def init_lm(key, cfg: ArchConfig) -> Dict:
             _init_layer(pkeys[i], cfg.layer_spec(i), cfg, dtype)
             for i in range(cfg.first_k_dense)
         ]
-    # scanned slots: stacked over num_superblocks via vmapped init
+    # scanned slots: stacked over num_superblocks, one layer at a time
     n_sb = cfg.num_superblocks
     slots = []
     for j, spec in enumerate(cfg.block_pattern):
         slot_keys = jax.random.split(jax.random.fold_in(keys[3], j), n_sb)
-        slots.append(jax.vmap(
-            lambda k, spec=spec: _init_layer(k, spec, cfg, dtype))(slot_keys))
+        slots.append(jax.lax.map(
+            lambda k, spec=spec: _init_layer(k, spec, cfg, dtype), slot_keys))
     params["slots"] = tuple(slots)
     # early-exit heads
     if cfg.early_exit is not None:
@@ -237,37 +244,56 @@ def _remat_wrap(fn, remat: str):
     return fn
 
 
+def _layer_at(stacked, i):
+    """Super-block ``i`` of a stacked pytree (traced index, no copy of the
+    rest of the stack)."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), stacked)
+
+
+def _set_layer(stacked, i, layer):
+    return jax.tree_util.tree_map(
+        lambda full, new: jax.lax.dynamic_update_index_in_dim(
+            full, new.astype(full.dtype), i, 0), stacked, layer)
+
+
 def _scan_segment(slots, x, sb_start, sb_end, cfg, policy, remat="nothing",
                   mode="train", states=None, cache_pos=None, page_table=None,
                   live=None):
-    """Run super-blocks [sb_start, sb_end). Returns (x, aux, new_states)."""
+    """Run super-blocks [sb_start, sb_end). Returns (x, aux, new_states).
+
+    The loop indexes layer i of the FULL stacked weights (and states, which
+    are updated in place: ``new_states`` is the full stack with the
+    segment's layers rewritten). Slicing a segment out of the stack copied
+    its weights on every call instead — 2.2 GB per FFN matrix for
+    chatglm3-6b's layers 7-28 behind the exit head, which does not fit on a
+    16 GB chip beside the model.
+    """
     if sb_end == sb_start:
         return x, jnp.zeros((), jnp.float32), states
-    sliced = jax.tree_util.tree_map(lambda a: a[sb_start:sb_end], slots)
-    xs = sliced
-    has_state = states is not None
-    if has_state:
-        states_sliced = jax.tree_util.tree_map(
-            lambda a: a[sb_start:sb_end], states)
-        xs = (sliced, states_sliced)
 
-    def body(carry, xs_i):
-        x, aux = carry
-        slot_params, slot_states = xs_i if has_state else (xs_i, None)
+    def body(carry, i):
+        x, aux, st = carry
+        slot_params = _layer_at(slots, i)
+        slot_states = None if st is None else _layer_at(st, i)
         new_states = []
         for j, spec in enumerate(cfg.block_pattern):
-            st = slot_states[j] if has_state else None
-            x, a, ns = _apply_layer(slot_params[j], x, spec, cfg, policy,
-                                    state=st, mode=mode, cache_pos=cache_pos,
-                                    page_table=page_table, live=live)
+            x, a, ns = _apply_layer(
+                slot_params[j], x, spec, cfg, policy,
+                state=None if slot_states is None else slot_states[j],
+                mode=mode, cache_pos=cache_pos, page_table=page_table,
+                live=live)
             aux = aux + a
             new_states.append(ns)
-        out = tuple(new_states) if has_state else None
-        return (x, aux), out
+        if st is not None:
+            st = _set_layer(st, i, tuple(new_states))
+        return (x, aux, st), None
 
     body = _remat_wrap(body, remat if mode == "train" else "nothing")
-    (x, aux), new_states = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
-    return x, aux, new_states
+    (x, aux, states), _ = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), states),
+        jnp.arange(sb_start, sb_end))
+    return x, aux, states
 
 
 # ---------------------------------------------------------------------------
@@ -702,15 +728,10 @@ def forward_decode(params, tokens, cfg: ArchConfig, policy: xaif.PolicyLike,
                                         policy)[:, 0])
     new_slots = cache.slots
     for sb_start, sb_end, exit_i in _segments(cfg):
-        x, _, seg_states = _scan_segment(
+        x, _, new_slots = _scan_segment(
             params["slots"], x, sb_start, sb_end, cfg, policy, mode="decode",
-            states=cache.slots, cache_pos=cache_pos, page_table=page_table,
+            states=new_slots, cache_pos=cache_pos, page_table=page_table,
             live=live)
-        if sb_end > sb_start:
-            new_slots = jax.tree_util.tree_map(
-                lambda full, seg: jax.lax.dynamic_update_slice_in_dim(
-                    full, seg.astype(full.dtype), sb_start, axis=0),
-                new_slots, seg_states)
         if exit_i is not None and (with_exits and cfg.early_exit is not None):
             exit_lg.append(_exit_logits(params, x, exit_i, cfg, policy)[:, 0])
     logits = _head(params, x, cfg, policy)[:, 0]
@@ -753,15 +774,10 @@ def forward_verify(params, tokens, cfg: ArchConfig, policy: xaif.PolicyLike,
         new_prefix.append(ns)
     new_slots = cache.slots
     for sb_start, sb_end, _exit_i in _segments(cfg):
-        x, _, seg_states = _scan_segment(
+        x, _, new_slots = _scan_segment(
             params["slots"], x, sb_start, sb_end, cfg, policy, mode="verify",
-            states=cache.slots, cache_pos=cache_pos, page_table=page_table,
+            states=new_slots, cache_pos=cache_pos, page_table=page_table,
             live=live)
-        if sb_end > sb_start:
-            new_slots = jax.tree_util.tree_map(
-                lambda full, seg: jax.lax.dynamic_update_slice_in_dim(
-                    full, seg.astype(full.dtype), sb_start, axis=0),
-                new_slots, seg_states)
     logits = _head(params, x, cfg, policy)                   # [B, K1, V]
     if paged:
         new_cache = PagedLMCache(tuple(new_prefix), new_slots, cache.pos,
@@ -837,68 +853,40 @@ def forward_decode_gated(params, tokens, cfg: ArchConfig, policy: xaif.PolicyLik
     exit_sb = (cfg.early_exit.exit_layers[0] - cfg.first_k_dense) // cfg.period
     n_sb = cfg.num_superblocks
     # segment 1: up to the exit head
-    x, _, pre_states = _scan_segment(params["slots"], x, 0, exit_sb, cfg,
-                                     policy, mode="decode", states=cache.slots,
-                                     cache_pos=cache_pos, live=live)
+    x, _, states = _scan_segment(params["slots"], x, 0, exit_sb, cfg,
+                                 policy, mode="decode", states=cache.slots,
+                                 cache_pos=cache_pos, live=live)
     exit_lg = _exit_logits(params, x, 0, cfg, policy)[:, 0]
     exit_mask, _ = should_exit(exit_lg, cfg.early_exit.entropy_threshold, policy)
     gate = exit_mask if live is None else (exit_mask | ~live)
-    rest = jax.tree_util.tree_map(lambda a: a[exit_sb:n_sb], cache.slots)
 
     def cont(ops):
-        x_in, rest_states = ops
-        x2, _, new_rest = _scan_segment_pre(rest_states, params, x_in, exit_sb,
-                                            n_sb, cfg, policy, cache_pos,
-                                            live=live)
+        x_in, st = ops
+        x2, _, st = _scan_segment(params["slots"], x_in, exit_sb, n_sb, cfg,
+                                  policy, mode="decode", states=st,
+                                  cache_pos=cache_pos, live=live)
         lg = _head(params, x2, cfg, policy)[:, 0]
         lg = jnp.where(exit_mask[:, None], exit_lg, lg)
-        return lg, new_rest
+        return lg, st
 
     def skip(ops):
-        x_in, rest_states = ops
+        x_in, st = ops
 
-        def body(carry, xs_i):
-            slot_params, slot_states = xs_i
+        def body(st, i):
+            slot_params, slot_states = _layer_at(params["slots"], i), \
+                _layer_at(st, i)
             new_states = tuple(
-                _kv_propagate_layer(slot_params[j], carry, cfg, policy,
+                _kv_propagate_layer(slot_params[j], x_in, cfg, policy,
                                     slot_states[j], cache_pos)
                 for j in range(cfg.period))
-            return carry, new_states
+            return _set_layer(st, i, new_states), None
 
-        sliced = jax.tree_util.tree_map(
-            lambda a: a[exit_sb:n_sb], params["slots"])
-        _, new_rest = jax.lax.scan(body, x_in, (sliced, rest_states))
-        return exit_lg, new_rest
+        st, _ = jax.lax.scan(body, st, jnp.arange(exit_sb, n_sb))
+        return exit_lg, st
 
-    logits, new_rest = jax.lax.cond(jnp.all(gate), skip, cont, (x, rest))
-    new_slots = jax.tree_util.tree_map(
-        lambda pre, post: jnp.concatenate([pre, post], axis=0),
-        pre_states, new_rest)
+    logits, new_slots = jax.lax.cond(jnp.all(gate), skip, cont, (x, states))
     return logits, exit_mask, LMCache(tuple(new_prefix), new_slots,
                                       cache.pos + 1)
-
-
-def _scan_segment_pre(states_sliced, params, x, sb_start, sb_end, cfg, policy,
-                      cache_pos, live=None):
-    """Like _scan_segment(mode=decode) but takes pre-sliced states."""
-    sliced = jax.tree_util.tree_map(
-        lambda a: a[sb_start:sb_end], params["slots"])
-
-    def body(carry, xs_i):
-        x, aux = carry
-        slot_params, slot_states = xs_i
-        new_states = []
-        for j, spec in enumerate(cfg.block_pattern):
-            x, a, ns = _apply_layer(slot_params[j], x, spec, cfg, policy,
-                                    state=slot_states[j], mode="decode",
-                                    cache_pos=cache_pos, live=live)
-            aux = aux + a
-            new_states.append(ns)
-        return (x, aux), tuple(new_states)
-
-    (x, aux), new_states = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (sliced, states_sliced))
-    return x, aux, new_states
 
 
 # ---------------------------------------------------------------------------

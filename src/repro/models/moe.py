@@ -260,7 +260,15 @@ def apply_moe_decode(params, x: jax.Array, cfg: ArchConfig,
     y = y[:, None, :]                                              # [B, 1, d]
 
     if "shared" in params:
-        y = y + apply_mlp(params["shared"], x, policy).astype(jnp.float32)
+        # the shared expert rides the same op as one always-selected expert
+        # with gate 1: as a plain dense MLP its dot's per-row bits changed
+        # with the co-batch size on XLA:CPU (up to 5e-7), which broke the
+        # composition-independence contract above
+        sp = params["shared"]
+        y = y + xaif.call(
+            "moe_decode", policy, x[:, 0], jnp.zeros((b, 1), jnp.int32),
+            jnp.ones((b, 1), jnp.float32), sp["w_gate"][None],
+            sp["w_up"][None], sp["w_down"][None])[:, None, :]
 
     w = (jnp.ones((b,), jnp.float32) if valid is None
          else valid.astype(jnp.float32))

@@ -717,6 +717,11 @@ def make_spec_decode_chunk(run: RunConfig, draft_cfg: ArchConfig, k: int,
     return spec_decode_chunk
 
 
+def _init_params(key, cfg: ArchConfig, shardings=None):
+    kw = {} if shardings is None else dict(out_shardings=shardings)
+    return jax.jit(functools.partial(lm.init_lm, cfg=cfg), **kw)(key)
+
+
 class SlotEngine:
     """Jit lifecycle around the slot batch: one decode trace per capacity,
     one prefill trace per prompt-length bucket, donated caches.
@@ -883,11 +888,9 @@ class SlotEngine:
         self.draft_params = None
         self._draft_cache = None
         if spec is not None and not spec.share_params:
-            self.draft_params = lm.init_lm(
-                jax.random.PRNGKey(spec.draft_seed), self.draft_cfg)
-            if self._spec_shardings is not None:
-                self.draft_params = jax.device_put(
-                    self.draft_params, self._spec_shardings[0])
+            self.draft_params = _init_params(
+                jax.random.PRNGKey(spec.draft_seed), self.draft_cfg,
+                self._spec_shardings[0] if self._spec_shardings else None)
 
         if spec is None:
             decode_fn = make_decode_chunk(run, chunk, gated, self._sampler)
@@ -989,6 +992,13 @@ class SlotEngine:
             dparams_sh = shd.param_shardings(dparams_struct)
             dcache_sh = shd.cache_shardings(dcache_struct, self.capacity)
         return dparams_sh, dcache_sh
+
+    def init_params(self, key):
+        """Random target parameters from ``key``, built directly in the
+        engine's layout: on a mesh each device only ever holds its shard,
+        so a model larger than one device's memory can be served."""
+        return _init_params(key, self.run.arch,
+                            self._shardings[0] if self._shardings else None)
 
     def place_params(self, params):
         """device_put ``params`` per the engine's sharding, so repeated

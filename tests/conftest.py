@@ -15,17 +15,3 @@ needs_mesh = pytest.mark.skipif(
     jax.device_count() < 4,
     reason="mesh tests need a forced multi-device host "
            "(XLA_FLAGS=--xla_force_host_platform_device_count=4)")
-
-# Older jax (<=0.4.x) exposes shard_map under jax.experimental and spells
-# check_vma as check_rep; newer jax has jax.shard_map(check_vma=...).
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                          check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-    jax.shard_map = _compat_shard_map

@@ -324,7 +324,7 @@ def test_attn_decode_ref_mask_dtype_pinned_under_x64():
     v = jax.random.normal(jax.random.PRNGKey(2), (2, 2, 16, 8), jnp.float32)
     pos = jnp.array([3, 9], jnp.int32)
     base = attn_decode_ref(q, k, v, pos)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         wide = attn_decode_ref(q, k, v, pos)
     assert base.dtype == wide.dtype == jnp.float32
     np.testing.assert_array_equal(np.asarray(base), np.asarray(wide))
@@ -333,7 +333,7 @@ def test_attn_decode_ref_mask_dtype_pinned_under_x64():
     vp = v.reshape(4, 2, 8, 8)
     table = jnp.array([[0, 1], [2, 3]], jnp.int32)
     base = paged_attention_ref(q, kp, vp, table, pos)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         wide = paged_attention_ref(q, kp, vp, table, pos)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(wide))
 

@@ -6,6 +6,7 @@ import numpy as np
 
 from repro.configs.base import (AccelConfig, RunConfig, SHAPES_BY_NAME,
                                 get_arch)
+from repro.dist.sharding import make_mesh
 from repro.models import lm
 from repro.train.train_step import make_train_step
 
@@ -78,7 +79,7 @@ def test_wq8_sharding_rules_inherit():
     cfg = get_arch("yi-9b").reduced()
     params = jax.eval_shape(lambda: quantize_weights_int8(
         lm.init_lm(jax.random.PRNGKey(0), cfg)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with mesh, shd.shard_ctx(mesh, ShardingPolicy()):
         specs = shd.param_pspecs(params)
     wq_spec = specs["slots"][0]["mixer"]["wq"]

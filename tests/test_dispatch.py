@@ -2,6 +2,7 @@
 buckets, per-bucket backend + tuning selection, JSON round-trips, backend
 equivalence across every shape bucket, and the measured autotuner."""
 import dataclasses
+import json
 
 import jax
 import jax.numpy as jnp
@@ -132,7 +133,7 @@ def test_policy_json_roundtrip_lossless():
          ("gemm", "rows_l"): "pallas_int8",
          ("attention", "decode"): "blockwise",
          "rmsnorm": "pallas"},
-        interpret=False, default="ref")
+        default="ref")
     doc = pol.to_json()
     back = xaif.DispatchPolicy.from_json(doc)
     assert back == pol
@@ -141,6 +142,25 @@ def test_policy_json_roundtrip_lossless():
     # extra metadata (e.g. autotune measurements) is ignored on load
     with_meta = pol.to_json(measurements=[{"op": "gemm", "us": 1.0}])
     assert xaif.DispatchPolicy.from_json(with_meta) == pol
+
+
+def test_policy_file_cannot_set_interpret_mode():
+    """Interpret mode follows the platform only: a policy file that still
+    carries the retired ``interpret`` key (or smuggles it in as tuning)
+    changes nothing, and off the TPU the Pallas backend runs interpreted."""
+    doc = json.dumps({"version": 2, "interpret": False, "default": "ref",
+                      "rules": [{"op": "rmsnorm", "bucket": "*",
+                                 "backend": "pallas",
+                                 "tuning": {"interpret": 0}}]})
+    pol = xaif.DispatchPolicy.from_json(doc)
+    assert "interpret" not in json.loads(pol.to_json())
+    x = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
+    scale = jnp.ones((128,), jnp.float32)
+    assert xaif.pallas_interpret() == (jax.default_backend() != "tpu")
+    out = xaif.call("rmsnorm", pol, x, scale)
+    ref = xaif.call("rmsnorm", AccelConfig(), x, scale)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,7 @@ from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs.base import AccelConfig, RunConfig, SHAPES_BY_NAME, get_arch
 from repro.data.pipeline import lm_batches
 from repro.dist.fault import FaultEvent, ResilientLoop, run_with_restarts
+from repro.dist.sharding import make_mesh
 from repro.train.train_step import make_train_step
 
 
@@ -162,7 +163,7 @@ def test_elastic_restore_new_mesh(tmp_path):
     ck = Checkpointer(str(tmp_path))
     state = {"w": jnp.arange(16.0).reshape(4, 4)}
     ck.save(1, state)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = {"w": NamedSharding(mesh, P("data", None))}
     restored, _, _ = ck.restore(state, shardings=sh)
@@ -202,7 +203,7 @@ def test_compressed_psum_shardmap():
     """compressed_psum under shard_map on a 1-axis mesh == plain sum."""
     from repro.dist.collectives import compressed_psum
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("pod",))
+    mesh = make_mesh((n,), ("pod",))
     x = jax.random.normal(jax.random.PRNGKey(2), (n, 256))
     from jax.sharding import PartitionSpec as P
 

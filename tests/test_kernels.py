@@ -118,7 +118,8 @@ def test_entropy_extremes():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("b,hq,hkv,t,d", [(2, 8, 2, 128, 64), (1, 4, 4, 64, 32),
-                                          (2, 16, 8, 256, 64), (1, 2, 1, 96, 128)])
+                                          (2, 16, 8, 256, 64), (1, 2, 1, 96, 128),
+                                          (1, 4, 2, 37, 32)])   # odd T: padded q
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_matches_ref(b, hq, hkv, t, d, dtype):
     ks = jax.random.split(jax.random.PRNGKey(b * hq + t), 3)

@@ -12,6 +12,7 @@ import pytest
 from repro.configs.base import (AccelConfig, RunConfig, SHAPES_BY_NAME,
                                 get_arch)
 from repro.core import xaif
+from repro.dist.sharding import make_mesh
 from repro.models import lm
 from repro.serve.engine import SlotEngine, generate
 from repro.serve.paging import PageAllocator
@@ -106,7 +107,7 @@ def test_mesh_paged_engine_token_identity(name, shape):
                         page_size=8)
     ref = {r.rid: r.tokens
            for r in serve(single, params, _requests(cfg, 7)).requests}
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     engine = SlotEngine(run, capacity=4, max_len=32, chunk=4, paged=True,
                         page_size=8, mesh=mesh,
                         sharding=ShardingPolicy(fsdp=False))
@@ -122,7 +123,7 @@ def test_mesh_paged_pool_sharding_applied():
     from repro.configs.base import ShardingPolicy
     cfg = get_arch("chatglm3-6b").reduced()
     run = _run_for(cfg)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     engine = SlotEngine(run, capacity=4, max_len=32, chunk=4, paged=True,
                         page_size=8, mesh=mesh,
                         sharding=ShardingPolicy(fsdp=False))

@@ -18,6 +18,7 @@ import pytest
 
 from repro.configs.base import (AccelConfig, RunConfig, SHAPES_BY_NAME,
                                 get_arch)
+from repro.dist.sharding import make_mesh
 from repro.models import lm
 from repro.serve.engine import SlotEngine, generate, make_sampler
 from repro.serve.paging import PageAllocator, PrefixIndex
@@ -148,7 +149,7 @@ def test_sharing_engine_token_identity_on_mesh():
 
     outs = {}
     for mesh_on in (False, True):
-        mesh = (jax.make_mesh((2, 2), ("data", "model"))
+        mesh = (make_mesh((2, 2), ("data", "model"))
                 if mesh_on else None)
         engine = SlotEngine(run, capacity=4, max_len=64, chunk=4, paged=True,
                             page_size=8, num_pages=40, prefix_sharing=True,

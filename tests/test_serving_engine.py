@@ -10,6 +10,7 @@ import pytest
 
 from repro.configs.base import (AccelConfig, RunConfig, SHAPES_BY_NAME,
                                 get_arch)
+from repro.dist.sharding import make_mesh
 from repro.models import lm
 from repro.serve.engine import SlotEngine, generate
 from repro.serve.scheduler import Request, poisson_requests, serve
@@ -261,7 +262,7 @@ def test_cache_shardings_slot_batch_axis():
     cfg = get_arch("yi-9b").reduced()          # n_sb == 2
     batch = cfg.num_superblocks                # force the size collision
     cache = jax.eval_shape(lambda: lm.init_cache(cfg, batch, 16))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with mesh, shd.shard_ctx(mesh, ShardingPolicy()):
         sh = shd.cache_shardings(cache, batch)
     k_spec = sh.slots[0].k.spec                # [n_sb, B, Hkv, S, D]
@@ -298,7 +299,7 @@ def test_mesh_engine_token_identity_with_backfill(name, shape):
     single = SlotEngine(run, capacity=4, max_len=32, chunk=4)
     ref = serve(single, params, _requests(cfg, 7))
     ref_toks = {r.rid: r.tokens for r in ref.requests}
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     engine = SlotEngine(run, capacity=4, max_len=32, chunk=4,
                         mesh=mesh, sharding=_serve_policy())
     report = serve(engine, params, _requests(cfg, 7))
@@ -318,7 +319,7 @@ def test_mesh_moe_engine_token_identity_with_backfill():
     single = SlotEngine(run, capacity=4, max_len=32, chunk=4)
     ref = serve(single, params, _requests(cfg, 7))
     ref_toks = {r.rid: r.tokens for r in ref.requests}
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     engine = SlotEngine(run, capacity=4, max_len=32, chunk=4,
                         mesh=mesh, sharding=_serve_policy())
     report = serve(engine, params, _requests(cfg, 7))
@@ -333,7 +334,7 @@ def test_mesh_decode_caches_donated():
     cfg = get_arch("chatglm3-6b").reduced()
     run = _run_for(cfg)
     params = lm.init_lm(jax.random.PRNGKey(0), cfg)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     engine = SlotEngine(run, capacity=4, max_len=24, chunk=4,
                         mesh=mesh, sharding=_serve_policy())
     placed = engine.place_params(params)
@@ -421,7 +422,7 @@ def test_launch_serve_rejects_paged_gated_at_parse_time(monkeypatch, capsys):
     """``--paged --gated`` must die in argparse with an actionable message,
     not on a bare assert deep inside SlotEngine after the model is built."""
     from repro.launch import serve as serve_launch
-    monkeypatch.setattr("sys.argv", ["serve", "--arch", "yi-9b",
+    monkeypatch.setattr("sys.argv", ["serve", "--arch", "yi-9b", "--reduced",
                                      "--paged", "--gated"])
     with pytest.raises(SystemExit) as ei:
         serve_launch.main()

@@ -47,7 +47,7 @@ def test_no_ctx_param_shardings_asserts():
 def test_non_dividing_axis_is_dropped():
     """An axis that would not divide a dim is dropped (replicated), never
     padded — the predictable-layout contract."""
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = shd.make_mesh((2, 2), ("data", "model"))
     with mesh, shd.shard_ctx(mesh, ShardingPolicy()):
         assert shd.spec_for((4, 8), "batch", "tp") == P("data", "model")
         assert shd.spec_for((3, 8), "batch", "tp") == P(None, "model")
@@ -58,7 +58,7 @@ def test_non_dividing_axis_is_dropped():
 def test_dp_over_model_folds_model_into_batch():
     """dp_over_model: the model axis joins the data axes for ``batch`` and
     tp/sp/ep resolve to nothing (small-model serving mode)."""
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = shd.make_mesh((2, 2), ("data", "model"))
     with mesh, shd.shard_ctx(mesh, ShardingPolicy(dp_over_model=True)) as ctx:
         assert ctx.axis("batch") == ("data", "model")
         assert ctx.axis("tp") is None and ctx.axis("ep") is None
@@ -77,7 +77,7 @@ def test_dp_over_model_folds_model_into_batch():
 def test_cache_shardings_lmcache_slot_axis():
     cfg = get_arch("chatglm3-6b").reduced()
     cache = jax.eval_shape(lambda: lm.init_cache(cfg, 4, 16))
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = shd.make_mesh((2, 2), ("data", "model"))
     with mesh, shd.shard_ctx(mesh, ShardingPolicy()):
         sh = shd.cache_shardings(cache, 4)
     # stacked slot states: batch at axis 1 over data, stack axis free
@@ -91,7 +91,7 @@ def test_cache_shardings_paged_pools_and_table():
     """Paged pools shard the capacity-agnostic HEAD dim over tp (GQA),
     MLA latent pools stay replicated, the page table is replicated, and
     recurrent (hybrid) slot states keep the slot axis over data."""
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = shd.make_mesh((2, 2), ("data", "model"))
     # GQA arch: reduced chatglm3 has num_kv_heads divisible by tp=2
     cfg = get_arch("chatglm3-6b").reduced()
     cache = jax.eval_shape(
@@ -130,7 +130,7 @@ def test_cache_shardings_paged_nondividing_heads_replicate():
     replicated instead of erroring."""
     cfg = get_arch("chatglm3-6b").reduced()
     cache = jax.eval_shape(lambda: lm.init_paged_cache(cfg, 4, 32, 8, 9))
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = shd.make_mesh((1, 4), ("data", "model"))
     with mesh, shd.shard_ctx(mesh, ShardingPolicy()):
         sh = shd.cache_shardings(cache, 4)
     if cfg.num_kv_heads % 4 != 0:
@@ -143,7 +143,7 @@ def test_serve_shardings_state_replicated():
     cfg = get_arch("chatglm3-6b").reduced()
     cache, state = jax.eval_shape(
         lambda: (lm.init_cache(cfg, 4, 16), init_decode_state(4)))
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = shd.make_mesh((2, 2), ("data", "model"))
     with mesh, shd.shard_ctx(mesh, ShardingPolicy()):
         cache_sh, state_sh = shd.serve_shardings(cache, state, 4)
     for s in jax.tree_util.tree_leaves(
@@ -165,7 +165,7 @@ def test_engine_place_params_commits_shardings():
     run = RunConfig(arch=cfg, shape=SHAPES_BY_NAME["decode_32k"],
                     accel=AccelConfig())
     params = lm.init_lm(jax.random.PRNGKey(0), cfg)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = shd.make_mesh((2, 2), ("data", "model"))
     engine = SlotEngine(run, capacity=4, max_len=16, mesh=mesh,
                         sharding=ShardingPolicy(fsdp=False))
     placed = engine.place_params(params)

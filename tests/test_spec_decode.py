@@ -13,6 +13,7 @@ import pytest
 
 from repro.configs.base import (AccelConfig, RunConfig, SHAPES_BY_NAME,
                                 get_arch)
+from repro.dist.sharding import make_mesh
 from repro.models import lm
 from repro.serve.engine import SlotEngine, SpecConfig
 from repro.serve.scheduler import Request, serve
@@ -155,7 +156,7 @@ from conftest import needs_mesh  # noqa: E402
 def test_mesh_spec_token_identity_with_backfill(world, name, shape):
     from repro.configs.base import ShardingPolicy
     cfg, run, params, ref = world
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     eng = SlotEngine(run, capacity=3, max_len=32, chunk=2,
                      mesh=mesh, sharding=ShardingPolicy(fsdp=False),
                      spec=SpecConfig(draft_arch=cfg, **TIED))
@@ -171,7 +172,7 @@ def test_mesh_spec_independent_draft_identity(world):
     with a low-acceptance draft."""
     from repro.configs.base import ShardingPolicy
     cfg, run, params, ref = world
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     eng = SlotEngine(run, capacity=3, max_len=32, chunk=2,
                      mesh=mesh, sharding=ShardingPolicy(fsdp=False),
                      spec=SpecConfig(draft_arch=_draft_of(cfg), k=2))
@@ -323,7 +324,7 @@ def test_set_draft_params_validates(world):
 
 def _cli(monkeypatch, argv):
     from repro.launch import serve as serve_launch
-    monkeypatch.setattr("sys.argv", ["serve"] + argv)
+    monkeypatch.setattr("sys.argv", ["serve", "--reduced"] + argv)
     with pytest.raises(SystemExit) as ei:
         serve_launch.main()
     return ei.value.code

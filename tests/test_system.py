@@ -18,6 +18,7 @@ import pytest
 from repro.configs.base import (AccelConfig, RunConfig, SHAPES_BY_NAME,
                                 get_arch)
 from repro.core import xaif
+from repro.dist.sharding import make_mesh
 
 
 def test_training_learns_and_exit_head_tracks():
@@ -111,7 +112,7 @@ def test_sharded_matches_single_device():
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                               cfg.vocab_size)
     plain, _, _ = lm.forward_train(params, toks, cfg, AccelConfig())
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with mesh, shd.shard_ctx(mesh, ShardingPolicy()):
         fn = jax.jit(lambda p, t: lm.forward_train(p, t, cfg, AccelConfig())[0])
         sharded = fn(params, toks)
@@ -124,7 +125,7 @@ def test_pallas_kernel_inside_shard_map():
     """Kernels compose with shard_map (how they deploy on a real mesh)."""
     from jax.sharding import PartitionSpec as P
     from repro.kernels.rmsnorm import ops as rn
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     x = jax.random.normal(jax.random.PRNGKey(0), (64, 128))
     s = jnp.ones((128,))
     out = jax.shard_map(

@@ -135,8 +135,7 @@ def test_policy_routes_verify_backend():
     q, k, v, pos = _contig(3, s=64, k1=3)
     ref_pol = xaif.DispatchPolicy.make({("verify_decode", "kv_s"): "ref"})
     pal_pol = xaif.DispatchPolicy.make(
-        {("verify_decode", "kv_s"): ("pallas", {"bs": 32,
-                                                "interpret": True})})
+        {("verify_decode", "kv_s"): ("pallas", {"bs": 32})})
     a = xaif.call("verify_decode", ref_pol, q, k, v, pos)
     b = xaif.call("verify_decode", pal_pol, q, k, v, pos)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
