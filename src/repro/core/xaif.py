@@ -442,6 +442,10 @@ def call(op: str, policy: PolicyLike, *args, **kwargs):
     FaultEvent, and the call is re-dispatched to the fallback. Dispatch
     happens at trace time, so the jit trace completes with the fallback
     baked in — no partial graphs.
+
+    The backend that runs is traced inside ``jax.named_scope(
+    "xaif.<op>.<backend>")``, so its ops carry that name in their HLO
+    ``op_name`` metadata (no runtime cost, no numeric change).
     """
     _ensure_builtin_backends()
     if isinstance(policy, DispatchPolicy):
@@ -467,6 +471,12 @@ def call(op: str, policy: PolicyLike, *args, **kwargs):
             m["interpret"] = pallas_interpret()
         return m
 
+    def _run(e: BackendEntry):
+        # HLO metadata only (op_name): a device trace finds each op's
+        # kernels by this name, whatever their shapes or layouts
+        with jax.named_scope(f"xaif.{op}.{e.name}"):
+            return e.fn(*args, **_kwargs(e))
+
     breaker = _BREAKER
     if breaker is not None:
         pin = breaker.pinned.get((op, bucket))
@@ -474,12 +484,12 @@ def call(op: str, policy: PolicyLike, *args, **kwargs):
             entry = get_entry(op, pin)           # cell already degraded
         if entry.name != breaker.fallback:
             try:
-                return entry.fn(*args, **_kwargs(entry))
+                return _run(entry)
             except Exception as exc:             # noqa: BLE001 — degrade
                 breaker.trip(op, bucket, entry.name, exc)
                 entry = get_entry(op, breaker.fallback)
-            return entry.fn(*args, **_kwargs(entry))
-    return entry.fn(*args, **_kwargs(entry))
+            return _run(entry)
+    return _run(entry)
 
 
 # ---------------------------------------------------------------------------

@@ -125,5 +125,6 @@ def attn_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, hq, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="attn_decode",
         interpret=interpret,
     )(cache_pos, q, k, v)

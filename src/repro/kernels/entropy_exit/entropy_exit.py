@@ -90,6 +90,7 @@ def entropy_pallas(logits: jax.Array, *, bm: int = 256, bv: int = 2048,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="entropy_exit",
         interpret=interpret,
     )(logits)
     return out[:, 0]

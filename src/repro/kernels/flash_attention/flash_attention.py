@@ -113,5 +113,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)[:, :, :t]

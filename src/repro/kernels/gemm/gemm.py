@@ -78,6 +78,7 @@ def gemm_pallas(x: jax.Array, w: jax.Array, bias: Optional[jax.Array] = None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="gemm",
         interpret=interpret,
     )(*args)
 
@@ -135,5 +136,6 @@ def gemm_int8_pallas(xq: jax.Array, wq: jax.Array, x_scale: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="gemm_int8",
         interpret=interpret,
     )(xq, wq, x_scale, w_scale, b)

@@ -116,6 +116,7 @@ def moe_decode_pallas(x: jax.Array, expert_idx: jax.Array, gate: jax.Array,
         out_shape=jax.ShapeDtypeStruct((nb * bt, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name="moe_decode",
         interpret=interpret,
     )(blk_e, x_pad, w_gate, w_up, w_down)
 

@@ -136,5 +136,6 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, hq, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="paged_attention",
         interpret=interpret,
     )(page_table, cache_pos, q, k_pages, v_pages)

@@ -37,5 +37,6 @@ def rmsnorm_pallas(x: jax.Array, scale: jax.Array, eps: float = 1e-5, *,
         ],
         out_specs=pl.BlockSpec((bm, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d), x.dtype),
+        name="rmsnorm",
         interpret=interpret,
     )(x, scale.reshape(1, d))

@@ -68,6 +68,7 @@ def mamba_decode_pallas(x, g, a, b, c, m, h, *, bd: int = 256,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
+        name="mamba_decode",
         interpret=interpret,
     )(x, g, a, b, c, m.reshape(1, din), h)
     return y, h_new
@@ -126,6 +127,7 @@ def mlstm_decode_pallas(x, g, a, b, c, m, h, n, *, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
+        name="mlstm_decode",
         interpret=interpret,
     )(col(x), col(g), row(a), one(b), one(c), one(m), h, col(n))
     return (h_out.reshape(bsz, hh, dh),

@@ -99,6 +99,7 @@ def selective_scan_pallas(u, dt, a, b, c, d, h0=None, *, bt: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="selective_scan",
         interpret=interpret,
     )(u, dt, a, b, c, d.reshape(1, din), h0)
     return y, h_fin

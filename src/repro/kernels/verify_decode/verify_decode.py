@@ -114,6 +114,7 @@ def verify_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, hq, k1, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="verify_decode",
         interpret=interpret,
     )(cache_pos, q, k, v)
 
@@ -202,5 +203,6 @@ def verify_decode_paged_pallas(q: jax.Array, k_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, hq, k1, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="verify_decode_paged",
         interpret=interpret,
     )(page_table, cache_pos, q, k_pages, v_pages)

@@ -336,6 +336,7 @@ def make_prefill_slot(run: RunConfig, bucket_len: int,
     """
     cfg, policy = run.arch, run.accel
 
+    @jax.named_scope("prefill")
     def prefill_slot(params, cache: lm.LMCache, st: DecodeState,
                      tokens, true_len, slot, max_new, rng0, has_seed):
         slot_cache = lm.init_cache(cfg, 1, bucket_len)
@@ -361,6 +362,7 @@ def make_prefill_slot_paged(run: RunConfig, bucket_len: int,
     the one trace per bucket)."""
     cfg, policy = run.arch, run.accel
 
+    @jax.named_scope("prefill")
     def prefill_slot(params, cache, st: DecodeState, tokens, true_len, slot,
                      max_new, page_ids, rng0, has_seed):
         slot_cache = lm.init_cache(cfg, 1, bucket_len)
@@ -389,6 +391,7 @@ def make_prefill_slot_shared(run: RunConfig, suffix_bucket: int,
     shared page)."""
     cfg, policy = run.arch, run.accel
 
+    @jax.named_scope("prefill")
     def prefill_slot(params, cache, st: DecodeState, tokens, start, n_prefix,
                      true_len, slot, max_new, prefix_ids, region_ids,
                      row_ids, rng0, has_seed):
@@ -418,6 +421,7 @@ def make_prefill_chunk(run: RunConfig, chunk_len: int, prefix_cap: int,
     cfg, policy = run.arch, run.accel
     n_region = chunk_len // page_size
 
+    @jax.named_scope("prefill")
     def prefill_chunk(params, cache, tokens, start, slot, prefix_ids,
                       region_ids, row_ids):
         ctx = attn.SharedPrefillCtx(prefix_ids, region_ids, start, start,
@@ -511,6 +515,7 @@ def make_decode_chunk(run: RunConfig, steps: int, gated: bool = False,
             realized=st.realized + jnp.sum(ok.astype(jnp.float32)))
         return (new_cache, st), next_tok
 
+    @jax.named_scope("decode_chunk")
     def decode_chunk(params, cache: lm.LMCache, st: DecodeState):
         (cache, st), toks = jax.lax.scan(
             functools.partial(body, params), (cache, st), None, length=steps)
@@ -696,6 +701,7 @@ def make_spec_decode_chunk(run: RunConfig, draft_cfg: ArchConfig, k: int,
             spec_acc=st.spec_acc + jnp.sum(a.astype(jnp.float32) * okf))
         return (vcache, dc, st), (emit, n_real)
 
+    @jax.named_scope("decode_chunk")
     def spec_decode_chunk(params, dparams, cache, dcache: lm.LMCache,
                           st: DecodeState):
         (cache, dcache, st), (emits, nreal) = jax.lax.scan(
@@ -895,7 +901,9 @@ class SlotEngine:
         if spec is None:
             decode_fn = make_decode_chunk(run, chunk, gated, self._sampler)
 
-            def counted_decode(params, cache, st):
+            # the function's name is the program's: jit_decode_chunk in
+            # the device trace's module line
+            def decode_chunk(params, cache, st):
                 self.decode_traces += 1      # runs at TRACE time only
                 return decode_fn(params, cache, st)
 
@@ -906,14 +914,14 @@ class SlotEngine:
                     in_shardings=(params_sh, cache_sh, state_sh),
                     out_shardings=(cache_sh, state_sh,
                                    NamedSharding(self.mesh, P(None, None))))
-            self._decode = jax.jit(self._traced(counted_decode),
+            self._decode = jax.jit(self._traced(decode_chunk),
                                    donate_argnums=(1, 2), **jit_kw)
         else:
             spec_fn = make_spec_decode_chunk(
                 run, self.draft_cfg, spec.k, chunk, self._sampler,
                 make_probs(temperature, top_k, top_p))
 
-            def counted_spec(params, dparams, cache, dcache, st):
+            def spec_decode_chunk(params, dparams, cache, dcache, st):
                 self.decode_traces += 1      # runs at TRACE time only
                 return spec_fn(params, dparams, cache, dcache, st)
 
@@ -926,7 +934,7 @@ class SlotEngine:
                                   dcache_sh, state_sh),
                     out_shardings=(cache_sh, dcache_sh, state_sh,
                                    NamedSharding(self.mesh, P(None, None))))
-            self._decode = jax.jit(self._traced(counted_spec),
+            self._decode = jax.jit(self._traced(spec_decode_chunk),
                                    donate_argnums=(2, 3, 4), **jit_kw)
         self._prefill = {}                   # bucket_len -> jitted fn
         self._draft_prefill = {}             # bucket_len -> jitted draft fn

@@ -21,6 +21,17 @@ The host's only per-chunk work is one fetch of (tokens, slot state) and the
 free-list bookkeeping; token validity is reconstructed from the per-slot
 generated counts, so no device round-trip happens inside the token loop.
 
+TRACING: ``serve()`` opens ``jax.profiler.TraceAnnotation`` spans, which
+record into a running profiler's trace on the device planes' clock and
+cost nothing without one. ``serve.admit`` (``rid``, ``bucket``) holds
+``serve.prefill`` and ``serve.first_token``; ``serve.chunk`` (``chunk``,
+``live``) holds ``serve.grow_pages``, ``serve.decode``, ``serve.fetch``
+and ``serve.retire``; ``serve.wait`` is the realtime sleep. Spans of one
+request share ``rid=``, spans of one chunk ``chunk=`` (from 0 in each
+stream). Always on, on the serve clock: ``ServeReport.chunks`` (one
+:class:`ChunkRecord` a chunk, ``index`` = the span's ``chunk``) and each
+request's ``wait_decode_s`` / ``wait_prefill_s``.
+
 OVERLOAD CONTROL: pass ``serve(..., overload=OverloadConfig(...))`` to run
 the stream through :class:`repro.serve.overload.OverloadScheduler` instead —
 priority-aged admission, optimistic paging with preemption (host swap or
@@ -30,15 +41,21 @@ baseline the overload benchmarks compare against.
 """
 from __future__ import annotations
 
+import bisect
+import itertools
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.serve.engine import SlotEngine
 from repro.serve.paging import PageAllocator
+
+# host span in a running profiler's trace; a no-op without one
+_span = jax.profiler.TraceAnnotation
 
 
 @dataclass
@@ -67,6 +84,11 @@ class Request:
     preemptions: int = 0
     tokens: List[int] = field(default_factory=list)
     itl: List[float] = field(default_factory=list)  # inter-token gaps (s)
+    # parts of [arrival, t_admitted] in which a decode chunk, or another
+    # request's admission, held the scheduler (s); the rest of the queue
+    # time waited for pool space or on the host
+    wait_decode_s: float = 0.0
+    wait_prefill_s: float = 0.0
 
     @property
     def latency(self) -> float:
@@ -89,12 +111,26 @@ def _pctiles(vals) -> Dict[str, float]:
             "mean": float(np.mean(a)), "max": float(np.max(a))}
 
 
+class ChunkRecord(NamedTuple):
+    """One decode chunk of a ``serve()`` stream, on the serve clock (s).
+    ``t_fetched - t_dispatched`` is the host's wait for the device; a chunk
+    that dispatched nothing has both at ``t_end``."""
+    index: int              # ``chunk=`` of its serve.* spans
+    t_start: float
+    t_dispatched: float     # the decode call returned
+    t_fetched: float        # its tokens and slot state reached the host
+    t_end: float            # retirement done
+    live: int               # occupied slots when the chunk began
+    produced: int           # valid tokens it produced
+
+
 @dataclass
 class ServeReport:
     requests: List[Request]
     wall_s: float
     decode_tokens: int
     stats: Dict[str, float]
+    chunks: List[ChunkRecord] = field(default_factory=list)
 
     @property
     def served(self) -> List[Request]:
@@ -221,6 +257,11 @@ class SlotScheduler:
         self.max_concurrency = 0                     # peak occupied slots
         self.shared_tokens = 0                       # prompt tokens NOT prefilled
         self.shared_admissions = 0                   # fork-point admissions
+        # serve-clock (start, end) of each admission that ran a prefill
+        self.admissions: List[Tuple[float, float]] = []
+        self.chunk_id = 0                            # set by serve()
+        # (t_dispatched, t_fetched) of the last step_chunk's decode
+        self.chunk_marks: Optional[Tuple[float, float]] = None
 
     def _now(self, fallback: float) -> float:
         return self.clock() if self.clock is not None else fallback
@@ -248,11 +289,22 @@ class SlotScheduler:
             return REJECTED
         if not self.free:
             return FULL
+        bucket = self.engine._bucket(t)
+        t0 = self._now(now)
+        with _span("serve.admit", rid=req.rid, bucket=bucket):
+            res = self._admit_free(req, now, prompt, budget, t, bucket)
+        if res == ADMITTED:
+            self.admissions.append((t0, self._now(now)))
+        return res
+
+    def _admit_free(self, req: Request, now: float, prompt: np.ndarray,
+                    budget: int, t: int, bucket: int) -> str:
+        """``admit`` once a slot is free: fork-point admission against the
+        prefix index, else a bucketed prefill. ADMITTED or FULL."""
         if self.alloc is not None and self.alloc.index is not None:
             res = self._admit_shared(req, now, prompt, budget, t)
             if res is not None:
                 return res                           # ADMITTED
-        bucket = self.engine._bucket(t)
         page_ids = None
         if self.alloc is not None:
             if not self.alloc.can_admit(bucket, t, budget):
@@ -261,9 +313,10 @@ class SlotScheduler:
             page_ids = self.alloc.admit(slot, bucket, t, budget)
         else:
             slot = self.free.popleft()
-        self.cache, self.state, tok0 = self.engine.prefill_into(
-            self.params, self.cache, self.state, prompt, slot,
-            budget, page_ids=page_ids, seed=req.seed)
+        with _span("serve.prefill", rid=req.rid):
+            self.cache, self.state, tok0 = self.engine.prefill_into(
+                self.params, self.cache, self.state, prompt, slot,
+                budget, page_ids=page_ids, seed=req.seed)
         # (the jitted fill wrote this slot's device table row; any OTHER
         # pending mirror changes — e.g. rows cleared by release() — keep
         # alloc.dirty set and are pushed before the next decode chunk.
@@ -305,10 +358,11 @@ class SlotScheduler:
             # touched by this slot
             self.cache = self.engine.copy_page(self.cache, int(boundary),
                                                int(region_ids[0]))
-        self.cache, self.state, tok0 = self.engine.prefill_into_shared(
-            self.params, self.cache, self.state, prompt, start, slot,
-            budget, prefix_ids, region_ids,
-            self.alloc.table[slot], seed=req.seed)
+        with _span("serve.prefill", rid=req.rid):
+            self.cache, self.state, tok0 = self.engine.prefill_into_shared(
+                self.params, self.cache, self.state, prompt, start, slot,
+                budget, prefix_ids, region_ids,
+                self.alloc.table[slot], seed=req.seed)
         self.alloc.register(prompt, slot)
         self.shared_tokens += start
         self.shared_admissions += 1
@@ -316,7 +370,8 @@ class SlotScheduler:
 
     def _finish_admit(self, req: Request, slot: int, tok0, now: float,
                       t: int, budget: int) -> str:
-        tok_i = int(tok0)                            # device sync: prefill done
+        with _span("serve.first_token", rid=req.rid):
+            tok_i = int(tok0)                        # device sync: prefill done
         t_tok = max(self._now(now), req.arrival)
         if req.t_admitted is None:
             req.t_admitted = now
@@ -398,17 +453,32 @@ class SlotScheduler:
 
     def step_chunk(self, now: float) -> int:
         """One jitted decode chunk + ONE host fetch; retire finished slots.
-        Returns the number of valid tokens produced this chunk."""
+        Returns the number of valid tokens produced this chunk and leaves
+        (t_dispatched, t_fetched) in ``chunk_marks``."""
+        c = self.chunk_id
         if self.alloc is not None:
-            self._grow_pages()
-        self.cache, self.state, toks = self.engine.decode(
-            self.params, self.cache, self.state)
-        # the single per-chunk host transfer:
-        toks_np = np.asarray(toks)
-        gen_np = np.asarray(self.state.generated)
-        done_np = np.asarray(self.state.done)
-        quar_np = np.asarray(self.state.quarantined)
+            with _span("serve.grow_pages", chunk=c):
+                self._grow_pages()
+        with _span("serve.decode", chunk=c):
+            self.cache, self.state, toks = self.engine.decode(
+                self.params, self.cache, self.state)
+        t_dispatched = self._now(now)
+        # the single per-chunk host transfer: the wait for the device
+        with _span("serve.fetch", chunk=c):
+            toks_np = np.asarray(toks)
+            gen_np = np.asarray(self.state.generated)
+            done_np = np.asarray(self.state.done)
+            quar_np = np.asarray(self.state.quarantined)
         t_tok = self._now(now)
+        self.chunk_marks = (t_dispatched, t_tok)
+        with _span("serve.retire", chunk=c):
+            return self._harvest(now, t_tok, toks_np, gen_np, done_np,
+                                 quar_np)
+
+    def _harvest(self, now: float, t_tok: float, toks_np, gen_np, done_np,
+                 quar_np) -> int:
+        """Record each live slot's fresh tokens from the chunk's host fetch
+        and retire the finished slots. Returns the valid tokens."""
         produced = 0
         for slot, req in list(self.occupant.items()):
             fresh = int(gen_np[slot]) - self._gen_seen[slot]
@@ -470,6 +540,50 @@ class SlotScheduler:
         return {}
 
 
+def _step(sched: SlotScheduler, chunks: List[ChunkRecord],
+          now: Callable[[], float]) -> int:
+    """One ``step_chunk`` inside its ``serve.chunk`` span, recorded in
+    ``chunks``. Returns the tokens it produced."""
+    i, live = len(chunks), len(sched.occupant)
+    sched.chunk_id, sched.chunk_marks = i, None
+    t_start = now()
+    with _span("serve.chunk", chunk=i, live=live):
+        produced = sched.step_chunk(t_start)
+    t_end = now()
+    t_dispatched, t_fetched = sched.chunk_marks or (t_end, t_end)
+    chunks.append(ChunkRecord(i, t_start, t_dispatched, t_fetched, t_end,
+                              live, produced))
+    return produced
+
+
+def _held(intervals: List[Tuple[float, float]]):
+    """``held(lo, hi)``: seconds of the ordered, disjoint ``intervals``
+    inside ``[lo, hi]``."""
+    starts = [a for a, _ in intervals]
+    ends = [b for _, b in intervals]
+    cum = list(itertools.accumulate(b - a for a, b in intervals))
+
+    def before(t: float) -> float:
+        i = bisect.bisect_right(starts, t)
+        return cum[i - 1] - max(ends[i - 1] - t, 0.0) if i else 0.0
+
+    return lambda lo, hi: max(before(hi) - before(lo), 0.0)
+
+
+def _attribute_waits(requests: List[Request], chunks: List[ChunkRecord],
+                     admissions: List[Tuple[float, float]]) -> None:
+    """Fill each admitted request's ``wait_decode_s`` and
+    ``wait_prefill_s`` from its queue time ``[arrival, t_admitted]``. Its
+    own admission starts at or after ``t_admitted``, so every admission
+    inside that interval is another request's."""
+    decode = _held([(c.t_start, c.t_end) for c in chunks])
+    prefill = _held(admissions)
+    for r in requests:
+        if r.t_admitted is not None:
+            r.wait_decode_s = decode(r.arrival, r.t_admitted)
+            r.wait_prefill_s = prefill(r.arrival, r.t_admitted)
+
+
 def serve(engine: SlotEngine, params, requests: List[Request],
           realtime: bool = False, overload=None) -> ServeReport:
     """Drive a request stream to completion.
@@ -497,16 +611,18 @@ def serve(engine: SlotEngine, params, requests: List[Request],
         return time.perf_counter() - t0
 
     sched.clock = now
+    chunks: List[ChunkRecord] = []
     while waiting or sched.busy:
         progressed = sched.admission_round(waiting, now(), realtime)
         if not sched.busy:
             if realtime and waiting:
-                time.sleep(max(waiting[0].arrival - now(), 0.0))
+                with _span("serve.wait"):
+                    time.sleep(max(waiting[0].arrival - now(), 0.0))
                 continue
             if not progressed:
                 break        # nothing running, nothing admissible: done
             continue
-        decode_tokens += sched.step_chunk(now())
+        decode_tokens += _step(sched, chunks, now)
     for req in waiting:
         # admission stalled with an idle batch: these can never be served
         if req.reject_reason is None:
@@ -514,6 +630,7 @@ def serve(engine: SlotEngine, params, requests: List[Request],
                 REASON_SHED, "unservable: needs more pages than an "
                 "idle pool can provide")
     wall = now()
+    _attribute_waits(requests, chunks, sched.admissions)
     # prefill-produced first tokens count toward throughput too
     total = decode_tokens + sum(1 for r in requests if r.tokens)
     stats = SlotEngine.stats(sched.state)
@@ -530,7 +647,7 @@ def serve(engine: SlotEngine, params, requests: List[Request],
         # park the warm pool for the NEXT serve() call (popped before reuse)
         engine.resident = (sched.cache, sched.state, sched.alloc)
     return ServeReport(requests=requests, wall_s=wall, decode_tokens=total,
-                       stats=stats)
+                       stats=stats, chunks=chunks)
 
 
 def poisson_requests(num: int, rate_hz: float, prompt_lens,

@@ -14,6 +14,17 @@ from repro.core import xaif
 from repro.core.autotune import CELLS, autotune
 
 
+@pytest.fixture
+def scratch_registry():
+    """Drop the backends a test registers when it ends: the registry is
+    process-wide, and a leftover throwaway backend fails the registry
+    audit of any later test in the same process."""
+    saved = dict(xaif._REGISTRY)
+    yield
+    xaif._REGISTRY.clear()
+    xaif._REGISTRY.update(saved)
+
+
 # ---------------------------------------------------------------------------
 # Hashability — policies as jit static arguments (regression: the seed's
 # AccelConfig held a raw dict, so hash() raised)
@@ -67,7 +78,7 @@ def test_shape_bucket_classes():
     assert xaif.shape_bucket("gemm", ()) == xaif.WILDCARD   # malformed
 
 
-def test_policy_selects_backend_and_tuning_per_bucket():
+def test_policy_selects_backend_and_tuning_per_bucket(scratch_registry):
     """A throwaway op registered with tunables shows the policy routing
     decode-shaped calls and prefill-shaped calls to different backends with
     the declared tuning injected (explicit kwargs win)."""
@@ -261,7 +272,7 @@ def test_supports_fallback_skips_rejecting_default():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
 
 
-def test_fallback_prefers_non_lossy():
+def test_fallback_prefers_non_lossy(scratch_registry):
     """When neither the rule backend nor default/ref accept the shapes,
     the last-resort fallback picks a non-lossy accepting backend before a
     lossy one."""
@@ -286,7 +297,7 @@ def test_fallback_prefers_non_lossy():
     np.testing.assert_array_equal(np.asarray(out), 0)   # not the lossy one
 
 
-def test_autotune_warns_on_ops_without_cells():
+def test_autotune_warns_on_ops_without_cells(scratch_registry):
     """An op registered outside the built-in cell table is reported, not
     silently left untuned; a caller-provided cell covers it."""
     @xaif.register("_test_nocell", "only")
