@@ -12,8 +12,10 @@ absorbed-decode variant — see ref.py). Two backends:
 
 * ``ref``    — gather-based jnp; BITWISE-identical to the contiguous decode
   paths (the paged engine's token-identity guarantee rests on it);
-* ``pallas`` — page-blocked kernel, one grid step per page-table entry with
-  the page id scalar-prefetched (no gather materialization).
+* ``pallas`` — page-blocked kernel, one grid step per slot looping over
+  the slot's live blocks of many pages, each page DMA'd by its
+  scalar-prefetched id (no gather materialization; entries past the live
+  length and free slots cost no copy and no math).
 """
 from __future__ import annotations
 
@@ -25,8 +27,10 @@ from repro.kernels.paged_attention import ref as _ref
 
 
 def paged_attention_cost(b, hq, np_, ps, d, dtype_bytes=2):
-    """Decode is bandwidth-bound on the resident pages: one pass over
-    [B, NP*ps] K and V lanes, one [B, Hq, D] query."""
+    """Decode is bandwidth-bound on the resident pages. From shapes alone
+    the lengths are unknown, so this counts every slot at the full table
+    width, [B, NP*ps] K and V lanes, and one [B, Hq, D] query: an upper
+    bound on what the kernel reads."""
     s = np_ * ps
     flops = 4.0 * b * hq * s * d
     return {"flops": flops,
@@ -45,7 +49,8 @@ def paged_attention_ref_op(q, k_pages, v_pages, page_table, cache_pos,
 
 @xaif.register("attn_decode_paged", "pallas", cost_fn=paged_attention_cost,
                description="page-blocked Pallas decode attention: one grid "
-                           "step per page, page ids scalar-prefetched")
+                           "step per slot over its live multi-page blocks, "
+                           "page ids scalar-prefetched")
 def paged_attention_pallas_op(q, k_pages, v_pages, page_table, cache_pos,
                               scale: Optional[float] = None, q2=None,
                               k2_pages=None, precise: bool = False, *,

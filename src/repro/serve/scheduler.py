@@ -262,6 +262,9 @@ class SlotScheduler:
         self.chunk_id = 0                            # set by serve()
         # (t_dispatched, t_fetched) of the last step_chunk's decode
         self.chunk_marks: Optional[Tuple[float, float]] = None
+        # per paged chunk: % of the page table's entries that hold a page,
+        # i.e. the share of the table paged decode attention reads
+        self.live_pages_pct: List[float] = []
 
     def _now(self, fallback: float) -> float:
         return self.clock() if self.clock is not None else fallback
@@ -459,6 +462,9 @@ class SlotScheduler:
         if self.alloc is not None:
             with _span("serve.grow_pages", chunk=c):
                 self._grow_pages()
+            table = self.alloc.table
+            self.live_pages_pct.append(
+                100.0 * np.count_nonzero(table >= 0) / table.size)
         with _span("serve.decode", chunk=c):
             self.cache, self.state, toks = self.engine.decode(
                 self.params, self.cache, self.state)
@@ -638,6 +644,9 @@ def serve(engine: SlotEngine, params, requests: List[Request],
     stats["prefill_tokens"] = float(engine.prefill_tokens)   # cumulative
     if sched.alloc is not None:
         stats["peak_pages"] = float(sched.alloc.peak_pages)
+        if sched.live_pages_pct:
+            stats["attn_live_pages_pct"] = float(
+                np.mean(sched.live_pages_pct))
         if sched.alloc.index is not None:
             stats["shared_tokens"] = float(sched.shared_tokens)
             stats["shared_admissions"] = float(sched.shared_admissions)

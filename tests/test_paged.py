@@ -3,6 +3,7 @@ admission, allocator invariants (no aliasing between live slots), the
 attn_decode_paged op/backends, slot-lifecycle round-trips and the
 over-long-prompt rejection regression."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -425,6 +426,131 @@ def test_paged_decode_ignores_junk_in_reused_pages():
     got = xaif.call("attn_decode_paged", ACCEL, q, jnp.asarray(poison_k),
                     jnp.asarray(poison_v), table, pos)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
+    # the Pallas kernel under NaN in the same lanes: p = 0 there must not
+    # let 0 * NaN into the output
+    from repro.kernels.paged_attention.paged_attention import \
+        paged_attention_pallas
+    clean = paged_attention_pallas(q, kp, vp, table, pos, interpret=True)
+    poison_k[poison_k == 1e9] = np.nan
+    poison_v[poison_v == -1e9] = np.nan
+    pal = paged_attention_pallas(q, jnp.asarray(poison_k),
+                                 jnp.asarray(poison_v), table, pos,
+                                 interpret=True)
+    assert np.isfinite(np.asarray(pal)).all()
+    np.testing.assert_array_equal(np.asarray(pal), np.asarray(clean))
+
+
+# Multi-page blocks: (cache_pos per slot, -1 for a free slot; Hq, Hkv; MLA)
+# at NP = 40 pages of 16, so a block of 16 pages spans 256 positions and a
+# full row runs three blocks, the last one half full.
+_EDGE_PS, _EDGE_NP = 16, 40
+_EDGE_CASES = {
+    "block_last_position": ([255, 3], 4, 2, False),
+    "next_block_first_position": ([256, 100], 4, 2, False),
+    "table_last_position": ([_EDGE_NP * _EDGE_PS - 1, 511], 4, 2, False),
+    "one_position": ([0, 300], 4, 2, False),
+    "free_slot": ([-1, 400, -1], 4, 2, False),
+    "chatglm3_heads": ([600, 17, 256], 32, 2, False),
+    "mla": ([255, 256, 0], 4, 1, True),
+}
+
+
+def _edge_fixture(key, cache_pos, hq, hkv, mla, d=16, rope=8):
+    """Pages scattered over the pool; each live slot's entries allocated a
+    few pages past its cache_pos (as page growth leaves them), a free slot
+    all -1 with cache_pos 0."""
+    b, ps, np_ = len(cache_pos), _EDGE_PS, _EDGE_NP
+    pool = b * np_ + 1
+    ks = jax.random.split(key, 6)
+    perm = np.asarray(jax.random.permutation(ks[0], np.arange(1, pool)))
+    table = np.full((b, np_), -1, np.int32)
+    for i, c in enumerate(cache_pos):
+        if c >= 0:
+            n = min(c // ps + 3, np_)
+            table[i, :n] = perm[i * np_:i * np_ + n]
+    pos = jnp.asarray(np.maximum(cache_pos, 0), jnp.int32)
+    q = jax.random.normal(ks[1], (b, hq, d))
+    kp = jax.random.normal(ks[2], (pool, hkv, ps, d))
+    vp = kp if mla else jax.random.normal(ks[3], (pool, hkv, ps, d))
+    extra = {}
+    if mla:
+        extra = dict(scale=0.2, q2=jax.random.normal(ks[4], (b, hq, rope)),
+                     k2_pages=jax.random.normal(ks[5], (pool, 1, ps, rope)),
+                     precise=True)
+    return q, kp, vp, jnp.asarray(table), pos, extra
+
+
+def _poison_unowned(pages, table, pos, ps):
+    """NaN in every page no row maps and in every lane past a row's
+    cache_pos, pages allocated ahead of it included."""
+    keep = np.zeros((pages.shape[0], ps), bool)
+    for row, c in zip(np.asarray(table), np.asarray(pos)):
+        for j, pid in enumerate(row):
+            if pid >= 0:
+                keep[pid] = np.arange(j * ps, (j + 1) * ps) <= c
+    return jnp.asarray(np.where(keep[:, None, :, None], np.asarray(pages),
+                                np.nan))
+
+
+def _pallas_jit():
+    """The kernel in interpret mode under one jit, so that calls of one
+    shape share a trace."""
+    from repro.kernels.paged_attention.paged_attention import \
+        paged_attention_pallas
+    return jax.jit(functools.partial(paged_attention_pallas, interpret=True),
+                   static_argnames=("scale", "precise"))
+
+
+@pytest.mark.parametrize("case", list(_EDGE_CASES))
+def test_paged_pallas_multi_page_blocks(case):
+    """Pallas (interpret mode) against the reference where blocks of many
+    pages begin and end, with NaN in every lane no live row owns."""
+    from repro.kernels.paged_attention.paged_attention import \
+        _pages_per_block
+    from repro.kernels.paged_attention.ref import paged_attention_ref
+    paged_attention_pallas = _pallas_jit()
+    cache_pos, hq, hkv, mla = _EDGE_CASES[case]
+    q, kp, vp, table, pos, extra = _edge_fixture(
+        jax.random.PRNGKey(7), cache_pos, hq, hkv, mla)
+    dk = q.shape[-1] + (extra["q2"].shape[-1] if mla else 0)
+    ppb = _pages_per_block(_EDGE_PS, hkv, dk, vp.shape[-1], _EDGE_NP, 4)
+    assert 1 < ppb < _EDGE_NP                  # several blocks, many pages
+    live = np.asarray(cache_pos) >= 0
+    ref = paged_attention_ref(q, kp, vp, table, pos, **extra)
+    pal = paged_attention_pallas(q, kp, vp, table, pos, **extra)
+    np.testing.assert_allclose(np.asarray(pal)[live], np.asarray(ref)[live],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(pal)[~live], 0.0)
+    if mla:
+        kn = _poison_unowned(kp, table, pos, _EDGE_PS)
+        extra["k2_pages"] = _poison_unowned(extra["k2_pages"], table, pos,
+                                            _EDGE_PS)
+        vn = kn
+    else:
+        kn = _poison_unowned(kp, table, pos, _EDGE_PS)
+        vn = _poison_unowned(vp, table, pos, _EDGE_PS)
+    got = paged_attention_pallas(q, kn, vn, table, pos, **extra)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(pal))
+
+
+def test_paged_pallas_entry_without_page_is_masked():
+    """An entry inside the live length that holds no page (-1) is masked
+    as the reference masks it, and NaN in the page it no longer maps, or
+    past cache_pos, stays out of the output."""
+    from repro.kernels.paged_attention.ref import paged_attention_ref
+    paged_attention_pallas = _pallas_jit()
+    q, kp, vp, table, pos, _ = _edge_fixture(
+        jax.random.PRNGKey(8), [400, 300], 4, 2, False)
+    table = table.at[0, 3].set(-1).at[1, 17].set(-1)
+    ref = paged_attention_ref(q, kp, vp, table, pos)
+    pal = paged_attention_pallas(q, kp, vp, table, pos)
+    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    got = paged_attention_pallas(q, _poison_unowned(kp, table, pos, 16),
+                                 _poison_unowned(vp, table, pos, 16), table,
+                                 pos)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(pal))
 
 
 # ---------------------------------------------------------------------------

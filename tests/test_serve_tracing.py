@@ -161,3 +161,30 @@ def test_decode_program_names_its_xaif_ops(backend):
     for op in ("attn_decode_paged", "gemm"):
         scope = f"xaif.{op}.{backend}"
         assert any(scope in n and "/decode_chunk/" in n for n in names), op
+
+
+@pytest.mark.parametrize("overload", [None, OverloadConfig()],
+                         ids=["slot", "overload"])
+def test_live_pages_pct_is_the_mean_share_of_table_entries(monkeypatch,
+                                                           overload):
+    """``stats["attn_live_pages_pct"]``: the mean over decode chunks of the
+    share of page-table entries that hold a page, as counted entry by entry
+    from the allocator's table when each chunk is dispatched."""
+    counted = []
+    decode = SlotEngine.decode
+
+    def counting_decode(self, params, cache, state):
+        table = np.asarray(cache.page_table)
+        held = sum(1 for row in table for pid in row if pid >= 0)
+        counted.append(100.0 * held / table.size)
+        return decode(self, params, cache, state)
+
+    monkeypatch.setattr(SlotEngine, "decode", counting_decode)
+    cfg, eng, params = _engine(capacity=3)
+    report = serve(eng, params, _requests(cfg, [5, 17, 9, 30],
+                                          [6, 3, 9, 4]),
+                   overload=overload)
+    pct = report.stats["attn_live_pages_pct"]
+    assert len(counted) == len(report.chunks) > 0
+    assert 0.0 < pct <= 100.0
+    assert pct == pytest.approx(float(np.mean(counted)))

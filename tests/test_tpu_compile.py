@@ -4,8 +4,10 @@ Each kernel is lowered through its ``ops.py`` wrapper with
 ``interpret=False`` at published widths — chatglm3-6b for the dense path
 (B=8 decode rows, 512-token prefill, 1024-token KV in pages of 16),
 qwen3-moe-30b-a3b for ``moe_decode`` and xlstm-350m for the mLSTM
-``ssm_decode`` cell — and compiled by the TPU compiler for one device of a
-described ``v5e:2x2`` topology. That catches what interpret mode cannot:
+``ssm_decode`` cell, plus paged decode attention at the benchmark cell's
+own size (64 slots, 160 pages of 16, a pool of 3,700) — and compiled by
+the TPU compiler for one device of a described ``v5e:2x2`` topology.
+That catches what interpret mode cannot:
 tiling the lowering refuses and scoped VMEM a kernel overruns. Nothing
 runs, so nothing here is a timing.
 
@@ -23,6 +25,9 @@ from jax.sharding import SingleDeviceSharding
 B, D, FF, V, HQ, HKV, DH, L, PS = 8, 4096, 13696, 65024, 32, 2, 128, 1024, 16
 NP = L // PS
 POOL = B * NP + 1
+# the benchmark's chatglm3-6b.chat cell: 64 slots, max_len 2560 in pages of
+# 16, a pool of 3,700 pages
+CELL_B, CELL_NP, CELL_POOL = 64, 2560 // PS, 3700
 
 
 @pytest.fixture(scope="module")
@@ -99,6 +104,12 @@ def _cases():
             lambda q, k, v, t, p: paged_attention_pallas_op(
                 q, k, v, t, p, interpret=False),
             [((B, HQ, DH), bf), pages, pages, table, pos]),
+        "attn_decode_paged_chatglm3": (
+            lambda q, k, v, t, p: paged_attention_pallas_op(
+                q, k, v, t, p, interpret=False),
+            [((CELL_B, HQ, DH), bf), ((CELL_POOL, HKV, PS, DH), bf),
+             ((CELL_POOL, HKV, PS, DH), bf), ((CELL_B, CELL_NP), i32),
+             ((CELL_B,), i32)]),
         "verify_decode": (
             lambda q, k, v, p: verify_decode_pallas_op(q, k, v, p,
                                                        interpret=False),
@@ -126,7 +137,8 @@ def _cases():
     "gemm_ffn_decode", "gemm_head_decode", "gemm_down_prefill",
     "rmsnorm_prefill", "rmsnorm_odd_rows", "flash_attention_prefill",
     "flash_attention_odd_len", "attn_decode", "attn_decode_paged",
-    "verify_decode", "verify_decode_paged", "entropy_exit",
+    "attn_decode_paged_chatglm3", "verify_decode", "verify_decode_paged",
+    "entropy_exit",
     "moe_decode_qwen3", "ssm_decode_mlstm_xlstm"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, specs = _cases()[name]
